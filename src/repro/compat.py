@@ -1,23 +1,12 @@
-"""Version-compatibility shims for JAX APIs that moved between releases.
+"""Shared ``shard_map`` entry point and the scan-in-manual-region rule.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the
-top-level ``jax`` namespace, and its replication-check kwarg was renamed
-``check_rep`` -> ``check_vma`` along the way; the manual-axes kwarg
-flipped polarity from ``auto`` (axes left automatic) to ``axis_names``
-(axes made manual).  Every module in this repo imports ``shard_map`` from
-here and speaks the *new* spelling; the wrapper translates for whichever
-JAX is installed.
+Every module in this repo imports ``shard_map`` from here, so the one
+place that knows the installed JAX's spelling (top-level ``jax.shard_map``
+with ``check_vma`` / ``axis_names``) is this file.
 """
 from __future__ import annotations
 
-try:  # jax >= 0.6: top-level export, check_vma / axis_names kwargs
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-
-    _NEW_API = True
-except ImportError:  # jax <= 0.5: experimental module, check_rep / auto
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _NEW_API = False
+from jax import shard_map as _shard_map
 
 
 def scan_safe_in_manual(mesh, manual_axes) -> bool:
@@ -44,22 +33,14 @@ def shard_map(
     in_specs,
     out_specs,
     check_vma: bool | None = None,
-    check_rep: bool | None = None,
     axis_names=None,
 ):
-    """``jax.shard_map`` with the new-API spelling on any JAX version."""
-    check = check_vma if check_vma is not None else check_rep
+    """``jax.shard_map``; ``axis_names`` may be any iterable of axes."""
     kwargs = {}
-    if _NEW_API:
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        if check is not None:
-            kwargs["check_vma"] = check
-    else:
-        if axis_names is not None:
-            kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-        if check is not None:
-            kwargs["check_rep"] = check
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    if check_vma is not None:
+        kwargs["check_vma"] = check_vma
     return _shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )

@@ -6,9 +6,10 @@ The serving hot path computes ``h = x @ w`` and immediately feeds ``h``
 kernels, the GEMM output round-trips HBM just to be re-read by the
 lookup.  This kernel applies the stacked LUT activation *in the matmul
 epilogue* while the output tile is still in VMEM: the grid blocks over
-output rows only (full K and N per step, so the in-kernel ``jnp.dot``
-performs the identical contraction the reference ``jnp.einsum`` does —
-bit-identical accumulation), the layer's bit-packed component slab is
+output rows and, where the weights would not fit VMEM whole, over output
+columns (full K per step, so every output element comes from the same
+full contraction the reference ``jnp.einsum`` performs), the layer's
+bit-packed component slab is
 staged by the scalar-prefetch layer id exactly like
 :func:`~repro.kernels.lut_act.lut_act_stacked_pallas`, and the gated form
 (``swiglu``-style ``act(gate) * up`` over a fused ``[gate|up]`` weight)
@@ -30,33 +31,55 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .lut_act import lut_eval_traced
+from .lut_act import SMEM_WHOLE, lut_eval_traced
+from .packing import lane_rows
 from .runtime import resolve_interpret
 
 
-def _fused_kernel(lid_ref, x_ref, w_ref, ust_ref, idx_ref, rsh_ref,
-                  bias_ref, lb_ref, mi_ref, mf_ref, out_ref, *,
-                  gated, any_lb, w_in, w_out, x_lo, x_hi, pack):
-    del lid_ref  # consumed by the index maps
+def _fused_kernel(lid_ref, x_ref, *refs, gated, any_lb, w_in, w_out, x_lo,
+                  x_hi, pack):
+    n_w = 2 if gated else 1
+    w_refs, refs = refs[:n_w], refs[n_w:]
+    ust_ref, idx_ref, rsh_ref, bias_ref, lb_ref, mi_ref, mf_ref, out_ref = refs
+    lid = lid_ref[0]
+    x = x_ref[...]
     # accumulate in f32 and round to the model dtype explicitly: the
     # unfused reference materializes the einsum output (one rounding to
     # x.dtype) before the LUT quantizer, and a dtype-out dot may legally
     # keep the f32 accumulation alive into the epilogue — which moves
     # values across quantization-bin edges and breaks bit-identity
-    h = jnp.dot(x_ref[...], w_ref[...],
-                preferred_element_type=jnp.float32).astype(out_ref.dtype)
-    if gated:
-        f = h.shape[1] // 2
-        gate, up = h[:, :f], h[:, f:]
-    else:
-        gate, up = h, None
+    dot = lambda w_ref: jnp.dot(
+        x, w_ref[...], preferred_element_type=jnp.float32
+    ).astype(out_ref.dtype)
+    gate = dot(w_refs[0])
     y = lut_eval_traced(
         gate, ust_ref[0], idx_ref[0], rsh_ref[0], bias_ref[0], lb_ref[0],
-        mi_ref[0, 0], mi_ref[0, 1], mi_ref[0, 2],
-        mf_ref[0, 0], mf_ref[0, 1],
+        mi_ref[lid, 0], mi_ref[lid, 1], mi_ref[lid, 2],
+        mf_ref[lid, 0], mf_ref[lid, 1],
         any_lb=any_lb, w_in=w_in, w_out=w_out, x_lo=x_lo, x_hi=x_hi,
         pack=pack, out_dtype=out_ref.dtype)
-    out_ref[...] = y * up if gated else y
+    out_ref[...] = y * dot(w_refs[1]) if gated else y
+
+
+# Bytes of weight blocks the fused kernel may hold in VMEM (double-buffered
+# gate and up blocks included), well inside the TPU's default scoped VMEM
+# limit.  Full-width weights of a 1024 x 2*3072 bf16 MLP would take ~24 MiB.
+_WEIGHT_VMEM_BUDGET = 8 << 20
+
+
+def _pick_block_n(k: int, n_out: int, itemsize: int, gated: bool) -> int:
+    """Widest output-column block whose weight tiles fit the VMEM budget:
+    all of ``n_out`` when it fits, else the widest 128-multiple divisor.
+    Every output element still contracts the full K in one dot."""
+    per_col = k * itemsize * (2 if gated else 1) * 2
+    if n_out * per_col <= _WEIGHT_VMEM_BUDGET:
+        return n_out
+    for bn in range(n_out - n_out % 128, 0, -128):
+        if n_out % bn == 0 and bn * per_col <= _WEIGHT_VMEM_BUDGET:
+            return bn
+    raise ValueError(
+        f"fused_matmul_lut: no 128-multiple column block of N={n_out} fits "
+        f"{_WEIGHT_VMEM_BUDGET} B of weight tiles at K={k}")
 
 
 def fused_matmul_lut_pallas(
@@ -92,18 +115,25 @@ def fused_matmul_lut_pallas(
             f"fused_matmul_lut: M={m} not a multiple of block_m={block_m} "
             f"(ops.fused_matmul_lut pads the token rows)")
     n_out = n // 2 if gated else n
-    row = lambda a: pl.BlockSpec((1,) + a.shape[1:],
-                                 lambda i, lid: (lid[0],) + (0,) * (a.ndim - 1))
+    bn = _pick_block_n(k, n_out, w.dtype.itemsize, gated)
+    n_blk = n_out // bn
+    tabs = [lane_rows(t) for t in (t_ust, t_idx, t_rsh, t_bias, t_lb)]
+    row = lambda a: pl.BlockSpec(
+        (1,) + a.shape[1:], lambda i, j, lid: (lid[0],) + (0,) * (a.ndim - 1))
+    # gate columns [j*bn, (j+1)*bn) and, gated, their up partners n_out on
+    w_specs = [pl.BlockSpec((k, bn), lambda i, j, lid: (0, j))]
+    if gated:
+        w_specs.append(pl.BlockSpec((k, bn), lambda i, j, lid: (0, j + n_blk)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(m // block_m,),
+        grid=(m // block_m, n_blk),
         in_specs=[
-            pl.BlockSpec((block_m, k), lambda i, lid: (i, 0)),
-            pl.BlockSpec((k, n), lambda i, lid: (0, 0)),
-            row(t_ust), row(t_idx), row(t_rsh), row(t_bias), row(t_lb),
-            row(meta_i), row(meta_f),
+            pl.BlockSpec((block_m, k), lambda i, j, lid: (i, 0)),
+            *w_specs,
+            *(row(t) for t in tabs),
+            SMEM_WHOLE, SMEM_WHOLE,
         ],
-        out_specs=pl.BlockSpec((block_m, n_out), lambda i, lid: (i, 0)),
+        out_specs=pl.BlockSpec((block_m, bn), lambda i, j, lid: (i, j)),
     )
     return pl.pallas_call(
         functools.partial(
@@ -113,7 +143,7 @@ def fused_matmul_lut_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n_out), x.dtype),
         interpret=interpret,
-    )(layer, x, w, t_ust, t_idx, t_rsh, t_bias, t_lb, meta_i, meta_f)
+    )(layer, x, *([w] * len(w_specs)), *tabs, meta_i, meta_f)
 
 
 def _as_stacked_parts(tab: dict):

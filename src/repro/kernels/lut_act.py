@@ -32,6 +32,11 @@ Every variant accepts bit-packed component slabs (``pack`` —
 unpacked in-kernel with one extra take + shift/mask, which keeps the
 VMEM-resident table bytes at the width the autotuner actually picked
 instead of 4 bytes per entry.
+
+The wrappers lay every component slab out as ``(…, R, 128)`` lane rows
+(:func:`~repro.kernels.packing.lane_rows`) and the kernels read them with
+:func:`~repro.kernels.packing.lane_take`, the one table lookup Mosaic
+compiles for the TPU.
 """
 from __future__ import annotations
 
@@ -42,15 +47,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .packing import unpack_take, unpack_take_traced
+from .packing import lane_rows, lane_take, unpack_take, unpack_take_traced
 from .runtime import resolve_interpret
+
+
+# Whole-array operand in scalar memory: the per-layer / per-site meta
+# tables, indexed in-kernel by the scalar-prefetch ids (a one-row VMEM
+# block of an (L, k) table is not a legal TPU tiling).
+SMEM_WHOLE = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _take(ref0, comp: str, idx, pack):
     """Component gather: direct take on raw int32 slabs, shift/mask unpack
     on bit-packed ones (``pack`` maps component -> static pack meta)."""
     if not pack or comp not in pack:
-        return jnp.take(ref0, idx, axis=0)
+        return lane_take(ref0, idx)
     p = pack[comp]
     return unpack_take(ref0, idx, width=p["width"], offset=p["offset"],
                        per_word=p["per_word"])
@@ -107,6 +118,7 @@ def lut_act_pallas(
             f"lut_act_pallas: rows={rows} not a multiple of "
             f"block_rows={block_rows}; trailing rows would be dropped by "
             f"the grid — pad the input (ops.lut_act does this)")
+    tabs = [lane_rows(t) for t in (t_ust, t_idx, t_rsh, t_bias, t_lb)]
     full = lambda a: pl.BlockSpec(a.shape, lambda i: (0,) * a.ndim)
     return pl.pallas_call(
         functools.partial(
@@ -116,12 +128,12 @@ def lut_act_pallas(
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
-            full(t_ust), full(t_idx), full(t_rsh), full(t_bias), full(t_lb),
+            *(full(t) for t in tabs),
         ],
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), x.dtype),
         interpret=interpret,
-    )(x, t_ust, t_idx, t_rsh, t_bias, t_lb)
+    )(x, *tabs)
 
 
 def lut_eval_traced(x, ust, idx_t, rsh, bias, lb, l, w_lb, w_hb,
@@ -160,13 +172,14 @@ def _stacked_kernel(lid_ref, x_ref, ust_ref, idx_ref, rsh_ref, bias_ref,
                     any_lb, w_in, w_out, x_lo, x_hi, pack):
     """Layer-indexed body: the table refs hold ONE layer's slab (selected
     by the scalar-prefetch layer id through the BlockSpec index maps) and
-    the per-layer scalars are traced values read from the meta rows —
-    same integer reconstruction math as :func:`_kernel`."""
-    del lid_ref  # consumed by the index maps
+    the per-layer scalars are traced values read from the layer's row of
+    the SMEM meta tables — same integer reconstruction math as
+    :func:`_kernel`."""
+    lid = lid_ref[0]
     out_ref[...] = lut_eval_traced(
         x_ref[...], ust_ref[0], idx_ref[0], rsh_ref[0], bias_ref[0],
-        lb_ref[0], mi_ref[0, 0], mi_ref[0, 1], mi_ref[0, 2],
-        mf_ref[0, 0], mf_ref[0, 1],
+        lb_ref[0], mi_ref[lid, 0], mi_ref[lid, 1], mi_ref[lid, 2],
+        mf_ref[lid, 0], mf_ref[lid, 1],
         any_lb=any_lb, w_in=w_in, w_out=w_out, x_lo=x_lo, x_hi=x_hi,
         pack=pack, out_dtype=out_ref.dtype)
 
@@ -198,14 +211,16 @@ def lut_act_stacked_pallas(
             f"lut_act_stacked_pallas: rows={rows} not a multiple of "
             f"block_rows={block_rows}; trailing rows would be dropped by "
             f"the grid — pad the input (ops.lut_act_stacked does this)")
-    row = lambda a: pl.BlockSpec((1, a.shape[1]), lambda i, lid: (lid[0], 0))
+    tabs = [lane_rows(t) for t in (t_ust, t_idx, t_rsh, t_bias, t_lb)]
+    row = lambda a: pl.BlockSpec((1,) + a.shape[1:],
+                                 lambda i, lid: (lid[0],) + (0,) * (a.ndim - 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, lanes), lambda i, lid: (i, 0)),
-            row(t_ust), row(t_idx), row(t_rsh), row(t_bias), row(t_lb),
-            row(meta_i), row(meta_f),
+            *(row(t) for t in tabs),
+            SMEM_WHOLE, SMEM_WHOLE,
         ],
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i, lid: (i, 0)),
     )
@@ -217,7 +232,7 @@ def lut_act_stacked_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, lanes), x.dtype),
         interpret=interpret,
-    )(layer, x, t_ust, t_idx, t_rsh, t_bias, t_lb, meta_i, meta_f)
+    )(layer, x, *tabs, meta_i, meta_f)
 
 
 def _multisite_kernel(sid_ref, lid_ref, x_ref, ust_ref, idx_ref, rsh_ref,
@@ -231,25 +246,26 @@ def _multisite_kernel(sid_ref, lid_ref, x_ref, ust_ref, idx_ref, rsh_ref,
     parameters from ``mp``.  The packed unpack runs with traced
     width/offset (``unpack_take_traced``), so one compiled kernel serves
     every site family."""
-    del sid_ref, lid_ref  # consumed by the index maps
-    l = mi_ref[0, 0, 0]
-    w_lb = mi_ref[0, 0, 1]
-    w_hb = mi_ref[0, 0, 2]
-    y_lo = mf_ref[0, 0, 0]
-    y_span = mf_ref[0, 0, 1]
-    x_lo = mf_ref[0, 0, 2]
+    sid = sid_ref[pl.program_id(0)]
+    lid = lid_ref[0]
+    l = mi_ref[sid, lid, 0]
+    w_lb = mi_ref[sid, lid, 1]
+    w_hb = mi_ref[sid, lid, 2]
+    y_lo = mf_ref[sid, lid, 0]
+    y_span = mf_ref[sid, lid, 1]
+    x_lo = mf_ref[sid, lid, 2]
     # reciprocals, not divisors: the static kernels' constant divisions
     # are strength-reduced by XLA into multiplies by the f32 reciprocal,
     # so the traced math multiplies by the same host-rounded reciprocals
     # (serve/stacked.py MultiSiteSlabs) to stay bit-identical
-    x_inv_span = mf_ref[0, 0, 3]
-    levels_in = mq_ref[0, 0]
-    inv_levels_out = mq_ref[0, 1]
+    x_inv_span = mf_ref[sid, lid, 3]
+    levels_in = mq_ref[sid, 0]
+    inv_levels_out = mq_ref[sid, 1]
 
     # component order matches packing.COMPONENTS
     take = lambda ci, ref, idx: unpack_take_traced(
-        ref[0, 0], idx, mp_ref[0, ci, 0], mp_ref[0, ci, 1],
-        mp_ref[0, ci, 2])
+        ref[0, 0], idx, mp_ref[sid, ci, 0], mp_ref[sid, ci, 1],
+        mp_ref[sid, ci, 2])
 
     x = x_ref[...]
     xn = jnp.clip((x.astype(jnp.float32) - x_lo) * x_inv_span, 0.0, 1.0)
@@ -310,19 +326,17 @@ def lut_act_multisite_pallas(
         raise ValueError(
             f"lut_act_multisite_pallas: block_sites {block_sites.shape} "
             f"must be ({n_blocks},) — one site id per row-block")
+    tabs = [lane_rows(t) for t in (t_ust, t_idx, t_rsh, t_bias, t_lb)]
     slab = lambda a: pl.BlockSpec(
-        (1, 1, a.shape[2]), lambda i, bs, lid: (bs[i], lid[0], 0))
+        (1, 1) + a.shape[2:],
+        lambda i, bs, lid: (bs[i], lid[0]) + (0,) * (a.ndim - 2))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((block_rows, lanes), lambda i, bs, lid: (i, 0)),
-            slab(t_ust), slab(t_idx), slab(t_rsh), slab(t_bias), slab(t_lb),
-            slab(meta_i), slab(meta_f),
-            pl.BlockSpec((1, meta_q.shape[1]),
-                         lambda i, bs, lid: (bs[i], 0)),
-            pl.BlockSpec((1,) + meta_p.shape[1:],
-                         lambda i, bs, lid: (bs[i], 0, 0)),
+            *(slab(t) for t in tabs),
+            SMEM_WHOLE, SMEM_WHOLE, SMEM_WHOLE, SMEM_WHOLE,
         ],
         out_specs=pl.BlockSpec((block_rows, lanes),
                                lambda i, bs, lid: (i, 0)),
@@ -332,5 +346,4 @@ def lut_act_multisite_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, lanes), x.dtype),
         interpret=interpret,
-    )(block_sites, layer, x, t_ust, t_idx, t_rsh, t_bias, t_lb,
-      meta_i, meta_f, meta_q, meta_p)
+    )(block_sites, layer, x, *tabs, meta_i, meta_f, meta_q, meta_p)

@@ -13,6 +13,12 @@ one int32 word, and the kernels unpack with one extra take + shift + mask
 (:func:`unpack_take` — shift/mask statics for the per-site kernels, traced
 metas for the multi-site single-grid kernel).
 
+Every in-kernel table read goes through :func:`lane_take`, the one
+component lookup Mosaic lowers: tables are laid out as ``(R, 128)`` lane
+rows (:func:`lane_rows`) and read with a per-row lane gather, because the
+TPU lowering accepts only same-shape 2-D gathers (a 1-D ``jnp.take`` on a
+VMEM table is refused with ``Only 2D gather is supported``).
+
 Packing is **lossless by construction** and round-trip asserted
 (``unpack_array(*pack_array(a)) == a``, hypothesis-tested for widths 2–16
 in tests/test_kernels_fused.py); the gather backend and every
@@ -33,6 +39,9 @@ COMPONENTS = ("t_ust", "t_idx", "t_rsh", "t_bias", "t_lb")
 # stores raw.  Plan components are bounded by w_out <= 16 bits in
 # practice, so the fallback is a safety valve, not a real path.
 MAX_PACK_WIDTH = 16
+
+# Lane width of a TPU vector register: the table row length lane_take reads.
+LANES = 128
 
 
 def needed_width(a: np.ndarray) -> tuple[int, int]:
@@ -97,19 +106,62 @@ def unpack_array(words: np.ndarray, meta: dict) -> np.ndarray:
     return (flat.astype(np.int64) + offset).astype(np.int32)
 
 
+def lane_rows(a):
+    """Lay a table out as lane rows: ``(..., n) -> (..., ceil(n/128), 128)``,
+    zero-padding the tail.  Free (a reshape) when ``n`` is already a
+    multiple of 128, as the raw int32 slabs are."""
+    import jax.numpy as jnp
+
+    n = a.shape[-1]
+    pad = (-n) % LANES
+    if pad:
+        a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+    return a.reshape(a.shape[:-1] + (-1, LANES))
+
+
+def lane_take(table, idx):
+    """Exact gather ``table.reshape(-1)[idx]`` from a lane-row table.
+
+    ``table`` is ``(R, 128)`` (:func:`lane_rows`), ``idx`` a 2-D int32
+    array of in-range, non-negative flat indices.  Each 128-lane column
+    chunk of ``idx`` gathers its lane from every table row broadcast to
+    the chunk's shape (``take_along_axis`` on the lane axis, which Mosaic
+    lowers to one dynamic gather per vreg) and keeps the row ``idx >> 7``
+    selects — ``R`` is at most 8 for a 10-bit table.  Pure integer
+    selection, so bit-identical to ``jnp.take`` on the flat table.
+    """
+    import jax.numpy as jnp
+
+    rows, cols = idx.shape
+    if rows == 1:
+        # the Mosaic gather lowering needs at least two sublanes
+        return lane_take(table, jnp.broadcast_to(idx, (2, cols)))[:1]
+    hi = jnp.right_shift(idx, 7)
+    lo = idx & (LANES - 1)
+    outs = []
+    for c0 in range(0, cols, LANES):
+        lo_c, hi_c = lo[:, c0:c0 + LANES], hi[:, c0:c0 + LANES]
+        acc = None
+        for r in range(table.shape[0]):
+            row = jnp.broadcast_to(table[r:r + 1], (rows, LANES))
+            got = jnp.take_along_axis(row, lo_c, axis=1)
+            acc = got if acc is None else jnp.where(hi_c == r, got, acc)
+        outs.append(acc)
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
 def unpack_take(words, idx, *, width: int, offset: int, per_word: int):
-    """Gather element ``idx`` out of a packed word row — the in-kernel
-    unpack with **static** shift/mask parameters (the per-site kernels).
+    """Gather element ``idx`` out of a packed lane-row word table — the
+    in-kernel unpack with **static** shift/mask parameters (the per-site
+    kernels).
 
     ``(word >> shift) & mask`` is correct under arithmetic right shift:
     the mask discards any sign-extension bits, so the extracted field
     equals the stored biased code regardless of the word's sign.
     """
-    import jax.numpy as jnp
-
     if width == 32:
-        return jnp.take(words, idx, axis=0)
-    w = jnp.take(words, idx // per_word, axis=0)
+        return lane_take(words, idx)
+    w = lane_take(words, idx // per_word)
     sh = (idx % per_word) * width
     return ((w >> sh) & ((1 << width) - 1)) + offset
 
@@ -123,7 +175,7 @@ def unpack_take_traced(words, idx, width, offset, per_word):
     """
     import jax.numpy as jnp
 
-    w = jnp.take(words, idx // per_word, axis=0)
+    w = lane_take(words, idx // per_word)
     sh = (idx % per_word) * width
     mask = jnp.left_shift(jnp.int32(1), width) - 1
     return (jnp.right_shift(w, sh) & mask) + offset

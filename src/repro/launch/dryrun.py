@@ -1,10 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any other import (jax locks the device
-count at first init).  For each cell we build the production mesh, lower
+``main`` gives the CPU backend 512 virtual devices before the first device
+query (XLA reads ``XLA_FLAGS`` when its client starts, so importing this
+module changes nothing).  For each cell we build the production mesh, lower
 the appropriate step (train_step / prefill / serve_step) against
 ShapeDtypeStruct inputs — no allocation — compile it, and record
 memory_analysis / cost_analysis / collective bytes for EXPERIMENTS.md.
@@ -16,6 +14,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -176,6 +175,7 @@ def dryrun_cell(arch: str, shape: str, multi_pod: bool,
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES)
     ap.add_argument("--shape", choices=list(SHAPES))

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Perf hillclimbing driver (EXPERIMENTS.md SSPerf).
 
 Runs named variants of the three selected (arch x shape) cells, re-lowers
@@ -13,6 +10,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 
 import numpy as np
@@ -134,6 +132,9 @@ EXPERIMENTS = [
 
 
 def main() -> None:
+    # 512 virtual CPU devices for the production mesh; set before the
+    # first device query, which is when XLA reads the flag
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     ap.add_argument("--skip-cached", action="store_true")

@@ -1,8 +1,19 @@
 """Production mesh construction (functions only — importing this module
-never touches jax device state)."""
+never touches jax device state).
+
+Every mesh is built with ``Auto`` axis types: the model code shards through
+GSPMD sharding constraints (``repro.nn.sharding.shard``), and JAX >= 0.7's
+default ``Explicit`` axes would instead demand an output sharding on every
+gather (``embed_lookup``'s ``jnp.take`` raises ``ShardingTypeError``).
+"""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -10,7 +21,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     "pod" axis (2 pods = 512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(dp: int = 1, tp: int = 1):
@@ -27,22 +38,7 @@ def make_host_mesh(dp: int = 1, tp: int = 1):
     if dp * tp > n:
         raise ValueError(
             f"make_host_mesh: mesh {dp}x{tp} needs {dp * tp} devices but "
-            f"only {n} are visible — set "
+            f"only {n} are visible (on the CPU backend, set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={dp * tp} "
-            f"before the first jax import (or shrink the mesh)")
-    return jax.make_mesh((dp, tp), ("data", "model"))
-
-
-def mesh_or_none(dp: int = 1, tp: int = 1):
-    """``make_host_mesh`` that degrades gracefully instead of raising.
-
-    Returns ``None`` for the trivial 1x1 request (no mesh machinery
-    needed) and for requests the visible device count cannot satisfy —
-    serve paths then fall back to the plain single-device program, which
-    is bit-identical to the sharded one by the mesh-suite contract.
-    """
-    if dp * tp <= 1:
-        return None
-    if dp < 1 or tp < 1 or dp * tp > len(jax.devices()):
-        return None
-    return make_host_mesh(dp, tp)
+            f"before the first device query; or shrink the mesh)")
+    return _auto_mesh((dp, tp), ("data", "model"))

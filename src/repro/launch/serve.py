@@ -49,21 +49,16 @@ from repro.calib import (
     synthetic_batches,
 )
 from repro.configs import ARCH_NAMES, get_config, smoke_config
-from repro.launch.mesh import mesh_or_none
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.nn import init_params
-from repro.serve import (
-    ShardedServe,
-    build_serving_plans,
-    decode_step,
-    init_cache,
-    prefill,
-    prefill_replay,
-)
+from repro.serve import ShardedServe, build_serving_plans, generate
 
 
 def main() -> None:
     ap = _build_parser()
     args = ap.parse_args()
+    enable_compile_cache()
     tel = None
     if args.obs_log:
         tel = obs.Telemetry(
@@ -147,10 +142,9 @@ def _build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--mesh", default=None, metavar="DP,TP",
                     help="serve on a (data, model) host mesh, e.g. 2,2 — "
                          "data-parallel batch x bit-exact tensor-parallel "
-                         "model with placed LUT tables; needs "
-                         "XLA_FLAGS=--xla_force_host_platform_device_count"
-                         "=N set before launch; degrades to single-device "
-                         "when the mesh cannot be built")
+                         "model with placed LUT tables; DP*TP devices must "
+                         "be visible (on the CPU: XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=N)")
     ap.add_argument("--mesh-mode", choices=("gspmd", "shard_map"),
                     default="gspmd",
                     help="sharded program form: gspmd partitioner "
@@ -188,14 +182,11 @@ def _main(ap, args, tel) -> None:
             dp, tp = (int(v) for v in args.mesh.split(","))
         except ValueError:
             ap.error(f"--mesh expects DP,TP (e.g. 2,2), got {args.mesh!r}")
-        mesh = mesh_or_none(dp, tp)
-        if mesh is None and dp * tp > 1:
-            log.warn("mesh_unavailable",
-                     f"mesh {dp}x{tp} unavailable "
-                     f"({len(jax.devices())} visible devices) — "
-                     f"serving single-device (bit-identical by contract)",
-                     dp=dp, tp=tp, devices=len(jax.devices()))
-        if mesh is not None and args.kv_int8 and args.mesh_mode == "shard_map":
+        try:
+            mesh = make_host_mesh(dp, tp)
+        except ValueError as e:
+            ap.error(f"--mesh: {e}")
+        if args.kv_int8 and args.mesh_mode == "shard_map":
             ap.error("--kv-int8 prefill replay is served in gspmd mesh "
                      "mode only (drop --kv-int8 or use --mesh-mode gspmd)")
 
@@ -324,13 +315,11 @@ def _main(ap, args, tel) -> None:
                            batch, lut_kernel, tel)
         return
 
-    max_seq = t + args.new_tokens
     serve = None
     if mesh is not None:
         serve = ShardedServe(cfg, mesh, lut_tables, mode=args.mesh_mode)
         params = serve.place_params(params)
         batch = serve.place_batch(batch)
-        lut_tables = serve.tables
         log.info("mesh_serving",
                  f"mesh {dict(mesh.shape)} mode={args.mesh_mode}; "
                  f"table placement:", mode=args.mesh_mode)
@@ -341,54 +330,28 @@ def _main(ap, args, tel) -> None:
                      f"{info['per_device_bytes']} B/dev)",
                      site=site, placement=info["placement"],
                      bytes=info["bytes"])
-
-    t0 = time.time()
-    with obs.span("prefill", batch=b, prompt_len=t):
-        if serve is not None:
-            logits, cache = serve.prefill(params, batch, max_seq)
-        else:
-            logits, cache = jax.jit(
-                lambda p, x: prefill(p, cfg, x, max_seq=max_seq,
-                                     lut_tables=lut_tables))(params, batch)
-    log.info("prefill", f"prefill {b}x{t}: {time.time() - t0:.2f}s",
-             seconds=round(time.time() - t0, 3))
-
     if args.kv_int8 and cfg.family in ("dense", "moe", "vlm"):
-        # re-home the prefill cache into int8 (write path quantizes) via
-        # one compiled replay scan instead of t python-level step calls
-        cache_q = init_cache(cfg, b, max_seq, kv_dtype="int8")
         log.info("kv_int8",
                  "int8 KV cache enabled (decode writes quantized entries)")
-        if serve is not None:
-            cache_q = serve.place_cache(cache_q)
-            logits, cache = serve.replay(params, cache_q, batch["tokens"])
-        else:
-            logits, cache = jax.jit(lambda p, c, tk: prefill_replay(
-                p, cfg, c, tk, 0, lut_tables=lut_tables))(
-                params, cache_q, batch["tokens"])
 
-    if serve is not None:
-        step = serve.decode
-    else:
-        step = jax.jit(lambda p, c, tk, pos: decode_step(
-            p, cfg, c, tk, pos, lut_tables=lut_tables))
-    tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-    outs = []
-    t0 = time.time()
-    with obs.span("decode", batch=b, new_tokens=args.new_tokens):
-        for i in range(args.new_tokens):
-            outs.append(np.asarray(tok)[:, 0])
-            logits, cache = step(params, cache, tok, jnp.asarray(t + i))
-            tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-    dt = time.time() - t0
+    gen = generate(cfg, params, batch, args.new_tokens,
+                   lut_tables=None if serve is not None else lut_tables,
+                   serve=serve, kv_int8=args.kv_int8)
+    log.info("prefill",
+             f"prefill {b}x{t}: compile {gen.prefill_compile_s:.2f}s, "
+             f"run {gen.prefill_s:.2f}s",
+             seconds=round(gen.prefill_s, 3),
+             compile_s=round(gen.prefill_compile_s, 3))
+    n = args.new_tokens * b
     log.info("decode",
-             f"decode {args.new_tokens} tokens x {b} requests: {dt:.2f}s "
-             f"({args.new_tokens * b / dt:.1f} tok/s)",
-             seconds=round(dt, 3),
-             tok_s=round(args.new_tokens * b / dt, 2))
-    log.info("request_tokens",
-             f"request 0: {[int(o[0]) for o in outs]}",
-             rid=0, tokens=[int(o[0]) for o in outs])
+             f"decode {args.new_tokens} tokens x {b} requests: compile "
+             f"{gen.decode_compile_s:.2f}s, run {gen.decode_s:.2f}s "
+             f"({n / gen.decode_s if gen.decode_s else 0.0:.1f} tok/s)",
+             seconds=round(gen.decode_s, 3),
+             compile_s=round(gen.decode_compile_s, 3),
+             tok_s=round(n / gen.decode_s, 2) if gen.decode_s else 0.0)
+    req0 = [int(v) for v in gen.tokens[0]]
+    log.info("request_tokens", f"request 0: {req0}", rid=0, tokens=req0)
 
 
 def _serve_with_reload(args, cfg, params, lut_tables, plan_source, batch,

@@ -21,10 +21,25 @@ from .layers import activation_fn, is_gated, logits_projection
 from .sharding import layer_scan, shard
 
 
+def _stored(x):
+    """``x`` exactly as a stored tensor of its dtype holds it.
+
+    The Pallas kernels read their input from memory and write their output
+    to memory, so they see and produce values rounded to the tensor's
+    dtype.  Inside one XLA fusion a bf16 intermediate may instead be
+    carried at f32 (``xla_allow_excess_precision``), so the gather
+    evaluators would quantize the unrounded matmul output and hand an
+    unrounded activation to the next op.  On a TPU v5e that changed nearly
+    every MLP output of a 28-layer decode; the barrier keeps the gather
+    form's boundary where the kernel's is."""
+    return jax.lax.optimization_barrier(x)
+
+
 def lut_act_jnp(x, arrays, *, l, w_lb, w_hb, w_in, w_out,
                 x_lo, x_hi, y_lo, y_hi):
     """GSPMD-friendly (gather-based) LUT activation, same math as the
     Pallas kernel / ref oracle."""
+    x = _stored(x)
     levels_in = (1 << w_in) - 1
     levels_out = (1 << w_out) - 1
     xn = jnp.clip((x.astype(jnp.float32) - x_lo) / (x_hi - x_lo), 0.0, 1.0)
@@ -40,7 +55,7 @@ def lut_act_jnp(x, arrays, *, l, w_lb, w_hb, w_in, w_out,
     if w_lb > 0:
         val = (val << w_lb) | jnp.take(arrays["t_lb"], code, axis=0)
     y = val.astype(jnp.float32) / levels_out * (y_hi - y_lo) + y_lo
-    return y.astype(x.dtype)
+    return _stored(y.astype(x.dtype))
 
 
 def lut_act_jnp_stacked(x, stacked: dict, layer):
@@ -54,6 +69,7 @@ def lut_act_jnp_stacked(x, stacked: dict, layer):
     span is pre-rounded host-side — is bit-identical to
     :func:`lut_act_jnp` on that layer's unstacked arrays.
     """
+    x = _stored(x)
     meta = stacked["meta"]
     layer = jnp.asarray(layer, jnp.int32)
     take_l = lambda a: jnp.take(a, layer, axis=0)
@@ -80,7 +96,7 @@ def lut_act_jnp_stacked(x, stacked: dict, layer):
         lb_val = jnp.take(arrays["t_lb"], code, axis=0)
         val = jnp.where(w_lb > 0, jnp.left_shift(val, w_lb) | lb_val, val)
     y = val.astype(jnp.float32) / levels_out * y_span + y_lo
-    return y.astype(x.dtype)
+    return _stored(y.astype(x.dtype))
 
 
 def tables_per_layer(lut_tables: dict | None) -> bool:
@@ -123,7 +139,11 @@ def run_layers(body, carry, xs, *, lut_tables=None, remat=False):
     resolve with ``jnp.take`` / scalar prefetch.  Only the legacy unrolled
     table form and activation capture still python-unroll with concrete
     indices (see :func:`needs_layer_ids`); the unrolled output pytree is
-    stacked to match the scan's exactly.
+    stacked to match the scan's exactly.  Each unrolled layer ends at an
+    optimization barrier, as each scan iteration ends at its stored carry:
+    otherwise XLA may fuse across layers and carry bf16 values at f32
+    into the next one, and a LUT bin edge then flips a token between the
+    two forms.
     """
     if needs_layer_ids(lut_tables):
         fn = jax.checkpoint(body, static_argnums=(2,)) if remat else body
@@ -131,6 +151,7 @@ def run_layers(body, carry, xs, *, lut_tables=None, remat=False):
         ys = []
         for i in range(length):
             carry, y = fn(carry, jax.tree.map(lambda a: a[i], xs), i)
+            carry, y = jax.lax.optimization_barrier((carry, y))
             ys.append(y)
         stacked = jax.tree.map(lambda *vs: jnp.stack(vs), *ys)
         return carry, stacked

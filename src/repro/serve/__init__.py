@@ -5,6 +5,7 @@ from .batching import ContinuousBatcher, Request
 from .decode import decode_step, prefill, prefill_replay
 from .degrade import RUNGS, CompositeSupervisor, DegradationLadder
 from .faults import FaultInjector, corrupt_file, corrupt_rung, corrupt_tables
+from .generate import Generation, generate
 from .kvcache import cache_shardings, cache_specs, init_cache
 from .plans import (
     ServingPlans,
@@ -24,7 +25,8 @@ from .sharded import (
 from .reload import PlanReloader, ReloadRecord
 from .stacked import StackedPlanArrays, tables_nbytes
 
-__all__ = ["prefill", "decode_step", "prefill_replay", "cache_specs",
+__all__ = ["prefill", "decode_step", "prefill_replay", "generate",
+           "Generation", "cache_specs",
            "init_cache", "cache_shardings", "ContinuousBatcher", "Request",
            "ServingPlans", "SitePlan", "StackedPlanArrays",
            "activation_sites", "build_serving_plans", "tables_nbytes",

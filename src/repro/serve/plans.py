@@ -50,7 +50,6 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -70,6 +69,8 @@ from repro.nn.lut_act import (
     activation_table,
     lut_activation_from_plan,
 )
+
+from .generate import generate
 
 # Engine search space for serving tables (same defaults as
 # nn.lut_act.build_lut_activation).
@@ -530,34 +531,6 @@ def build_serving_plans(
                         calib="per_site" if per_site else "shared")
 
 
-def _greedy_decode(cfg, params, batch, t, n_new, max_seq, tables,
-                   serve=None):
-    """(tokens per step, per-step logits) for one backend/tables config.
-
-    With ``serve`` (a :class:`~repro.serve.sharded.ShardedServe`) the
-    sharded jitted steps run; otherwise the plain single-device program.
-    """
-    from .decode import decode_step, prefill
-
-    if serve is not None:
-        lg, cache = serve.prefill(params, batch, max_seq)
-        step = lambda p, c, tk, pos: serve.decode(p, c, tk, pos)
-    else:
-        lg, cache = jax.jit(
-            lambda p, x: prefill(p, cfg, x, max_seq=max_seq,
-                                 lut_tables=tables))(params, batch)
-        step = jax.jit(lambda p, c, tk, pos: decode_step(
-            p, cfg, c, tk, pos, lut_tables=tables))
-    tok = jnp.argmax(lg[:, -1], -1).astype(jnp.int32)[:, None]
-    toks, logits = [], [np.asarray(lg[:, -1])]
-    for i in range(n_new):
-        toks.append(np.asarray(tok)[:, 0].tolist())
-        lg, cache = step(params, cache, tok, jnp.asarray(t + i))
-        logits.append(np.asarray(lg[:, -1]))
-        tok = jnp.argmax(lg[:, -1], -1).astype(jnp.int32)[:, None]
-    return toks, logits
-
-
 def verify_backend_equivalence(
     cfg: ArchConfig,
     params,
@@ -604,18 +577,14 @@ def verify_backend_equivalence(
         batch = {k: jnp.asarray(v) for k, v in prompt.items()}
     else:
         batch = {"tokens": jnp.asarray(prompt, jnp.int32)}
-    b, t = batch["tokens"].shape
-    if cfg.family == "vlm" and "patches" in batch:
-        t = t + batch["patches"].shape[1]   # patch prefix occupies the cache
-    max_seq = max_seq or (t + n_new)
+    b = batch["tokens"].shape[0]
     outs: dict[str, list[list[int]]] = {}
     for backend in ("gather", "pallas"):
         tables = plans.tables_for_model(backend=backend,
                                         plan_exec=plan_exec, mesh=False)
-        toks, logits = _greedy_decode(cfg, params, batch, t, n_new,
-                                      max_seq, tables)
-        outs[backend] = [[toks[i][r] for i in range(n_new)]
-                         for r in range(b)]
+        ref = generate(cfg, params, batch, n_new, lut_tables=tables,
+                       max_seq=max_seq, all_logits=True)
+        outs[backend] = ref.tokens.tolist()
         if mesh is None:
             continue
         from .sharded import ShardedServe
@@ -626,27 +595,27 @@ def verify_backend_equivalence(
                                               plan_exec=plan_exec,
                                               mesh=mesh)
         serve = ShardedServe(cfg, mesh, s_tables)
-        s_toks, s_logits = _greedy_decode(
-            cfg, serve.place_params(params), serve.place_batch(batch), t,
-            n_new, max_seq, None, serve=serve)
-        assert s_toks == toks, (
+        got = generate(cfg, serve.place_params(params),
+                       serve.place_batch(batch), n_new, serve=serve,
+                       max_seq=max_seq, all_logits=True)
+        assert np.array_equal(got.tokens, ref.tokens), (
             f"sharded {backend} decode diverges from the single-device "
-            f"reference: {s_toks} != {toks}")
+            f"reference: {got.tokens.tolist()} != {ref.tokens.tolist()}")
         n_data = 1
         for ax in ("pod", "data"):
             n_data *= int(mesh.shape.get(ax, 1))
         bits = n_data == 1 or (b % n_data == 0 and b // n_data >= 2)
-        for i, (ref, got) in enumerate(zip(logits, s_logits)):
+        for i, (r_lg, s_lg) in enumerate(zip(ref.logits, got.logits)):
             if bits:
-                assert np.array_equal(ref, got), (
+                assert np.array_equal(r_lg, s_lg), (
                     f"sharded {backend} logits not bit-identical to the "
                     f"single-device reference at step {i} "
-                    f"(max |diff| {np.max(np.abs(ref - got))})")
+                    f"(max |diff| {np.max(np.abs(r_lg - s_lg))})")
             else:
-                assert np.allclose(ref, got, rtol=0, atol=1e-4), (
+                assert np.allclose(r_lg, s_lg, rtol=0, atol=1e-4), (
                     f"sharded {backend} logits diverge from the "
                     f"single-device reference at step {i} beyond ulp "
-                    f"tolerance (max |diff| {np.max(np.abs(ref - got))})")
+                    f"tolerance (max |diff| {np.max(np.abs(r_lg - s_lg))})")
     # Fused hot path: matmul-epilogue LUT fusion (cfg.lut_fuse) over the
     # multi-site super-slab (kernel="fused", stacked exec) or the isolated
     # packed entries (unrolled exec) — asserted token-for-token
@@ -656,9 +625,8 @@ def verify_backend_equivalence(
     f_tables = plans.tables_for_model(backend="pallas", plan_exec=plan_exec,
                                       mesh=False, kernel=fused_kernel)
     f_cfg = dataclasses.replace(cfg, lut_fuse=True)
-    f_toks, _ = _greedy_decode(f_cfg, params, batch, t, n_new, max_seq,
-                               f_tables)
-    f_out = [[f_toks[i][r] for i in range(n_new)] for r in range(b)]
+    f_out = generate(f_cfg, params, batch, n_new, lut_tables=f_tables,
+                     max_seq=max_seq).tokens.tolist()
     for r, (a, bb) in enumerate(zip(outs["gather"], outs["pallas"])):
         assert a == bb, (
             f"backend divergence on request {r}: gather={a} pallas={bb}")

@@ -482,12 +482,6 @@ class ShardedServe:
         self._jit_step = jax.jit(_step)
 
     # -- public API --------------------------------------------------------
-    def prefill(self, params, batch: dict, max_seq: int):
-        if self.mode == "gspmd":
-            return self._prefill(params, batch, max_seq, self._tab_leaves)
-        return self._manual_prefill(params, batch, max_seq,
-                                    self._tab_leaves)
-
     def decode(self, params, cache, tok, pos):
         if self.mode == "gspmd":
             return self._step(params, cache, tok, pos, self._tab_leaves)
@@ -500,6 +494,19 @@ class ShardedServe:
                 "prefill replay is served in gspmd mode only")
         return self._replay(params, cache, tokens, start_pos,
                             self._tab_leaves)
+
+    @property
+    def table_operands(self) -> list:
+        """The placed table slabs, passed as the last argument of every
+        program this object lowers (``lower_prefill`` / ``lower_decode``:
+        call the compiled program as ``exe(*args, serve.table_operands)``
+        without the static ``max_seq``)."""
+        return self._tab_leaves
+
+    def lower_prefill(self, params, batch: dict, max_seq: int):
+        """Lower (no compile) the prefill program for ``max_seq``."""
+        fn = self._prefill if self.mode == "gspmd" else self._manual_prefill
+        return fn.lower(params, batch, max_seq, self._tab_leaves)
 
     def lower_decode(self, params, cache, tok, pos):
         """Lower (no compile) one decode step — the mesh suite's HLO /

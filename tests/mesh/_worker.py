@@ -34,7 +34,7 @@ from repro.calib import (
     synthetic_batches,
 )
 from repro.configs import get_config, smoke_config
-from repro.launch.mesh import make_host_mesh, mesh_or_none
+from repro.launch.mesh import make_host_mesh
 from repro.nn import init_params
 from repro.serve import build_serving_plans, verify_backend_equivalence
 
@@ -138,7 +138,7 @@ def scenario_shard_map(arch: str = "qwen3-0.6b", n_new: int = 3):
     single-device program, and ``layer_scan`` keeps ``lax.scan`` (no
     python-unroll) because the region is manual over every mesh axis."""
     from repro.nn.sharding import SCAN_STATS
-    from repro.serve.plans import _greedy_decode
+    from repro.serve import generate
     from repro.serve.sharded import ShardedServe
 
     cfg, params, plans, batch_d = _setup(arch, per_site=True)
@@ -146,23 +146,22 @@ def scenario_shard_map(arch: str = "qwen3-0.6b", n_new: int = 3):
     tables = plans.tables_for_model(backend="gather", mesh=False)
     batch_j = {k: jnp.asarray(v) for k, v in batch_d.items()}
     b, t = batch_j["tokens"].shape
-    max_seq = t + n_new
-    ref_toks, ref_logits = _greedy_decode(cfg, params, batch_j, t, n_new,
-                                          max_seq, tables)
+    ref = generate(cfg, params, batch_j, n_new, lut_tables=tables,
+                   all_logits=True)
 
     before = dict(SCAN_STATS)
     serve = ShardedServe(cfg, mesh, tables, mode="shard_map")
     # manual mode replicates every table slab
     assert all(r["placement"] == "replicated"
                for r in serve.placement.values()), serve.placement
-    s_toks, s_logits = _greedy_decode(
-        cfg, serve.place_params(params), serve.place_batch(batch_j), t,
-        n_new, max_seq, None, serve=serve)
+    p_sh, b_sh = serve.place_params(params), serve.place_batch(batch_j)
+    got = generate(cfg, p_sh, b_sh, n_new, serve=serve, all_logits=True)
     after = dict(SCAN_STATS)
-    assert s_toks == ref_toks, (
-        f"shard_map decode diverges: {s_toks} != {ref_toks}")
+    ref_toks = ref.tokens.tolist()
+    assert got.tokens.tolist() == ref_toks, (
+        f"shard_map decode diverges: {got.tokens.tolist()} != {ref_toks}")
     max_diff = max(float(np.max(np.abs(r - s)))
-                   for r, s in zip(ref_logits, s_logits))
+                   for r, s in zip(ref.logits, got.logits))
     # per-device batch is b/dp >= 2 here, but manual mode computes at
     # per-shard shapes by construction — hold logits to the same ulp
     # tolerance the gspmd one-example-shard case gets
@@ -171,11 +170,10 @@ def scenario_shard_map(arch: str = "qwen3-0.6b", n_new: int = 3):
         "fully-manual serving must not python-unroll the layer stacks")
     assert after["scan"] > before["scan"]
 
-    cache = serve.prefill(serve.place_params(params),
-                          serve.place_batch(batch_j), max_seq)[1]
+    cache = serve.lower_prefill(p_sh, b_sh, t + n_new).compile()(
+        p_sh, b_sh, serve.table_operands)[1]
     tok = jnp.zeros((b, 1), jnp.int32)
-    hlo = serve.lower_decode(serve.place_params(params), cache, tok,
-                             t).as_text()
+    hlo = serve.lower_decode(p_sh, cache, tok, t).as_text()
     assert "while" in hlo, "manual decode should lower layer stacks to while"
     return {"tokens": ref_toks, "max_logit_diff": max_diff,
             "scan_stats": after}
@@ -184,7 +182,7 @@ def scenario_shard_map(arch: str = "qwen3-0.6b", n_new: int = 3):
 def scenario_tuned(arch: str = "qwen3-0.6b", n_new: int = 4):
     """A saved+reloaded tuned-plan artifact (repro.tune) serves under a
     mesh bit-identically to its single-device decode."""
-    from repro.serve.plans import _greedy_decode
+    from repro.serve import generate
     from repro.serve.sharded import ShardedServe
     from repro.tune import (
         SweepPoint,
@@ -213,24 +211,22 @@ def scenario_tuned(arch: str = "qwen3-0.6b", n_new: int = 4):
     cfg = loaded.patched_config(cfg)
     batch_j = {k: jnp.asarray(v)
                for k, v in model_batch(cfg, rng, 4, 8).items()}
-    b, t = batch_j["tokens"].shape
-    max_seq = t + n_new
     mesh = make_host_mesh(2, 2)
     toks_by_backend = {}
     for backend in ("gather", "pallas"):
         tables = loaded.tables_for_model(backend=backend)
-        ref_toks, ref_logits = _greedy_decode(cfg, params, batch_j, t,
-                                              n_new, max_seq, tables)
+        ref = generate(cfg, params, batch_j, n_new, lut_tables=tables,
+                       all_logits=True)
         serve = ShardedServe(cfg, mesh, tables)
-        s_toks, s_logits = _greedy_decode(
-            cfg, serve.place_params(params), serve.place_batch(batch_j), t,
-            n_new, max_seq, None, serve=serve)
-        assert s_toks == ref_toks, (
+        got = generate(cfg, serve.place_params(params),
+                       serve.place_batch(batch_j), n_new, serve=serve,
+                       all_logits=True)
+        assert np.array_equal(got.tokens, ref.tokens), (
             f"sharded tuned-plan decode [{backend}] diverges")
-        for i, (r, s) in enumerate(zip(ref_logits, s_logits)):
+        for i, (r, s) in enumerate(zip(ref.logits, got.logits)):
             assert np.array_equal(r, s), (
                 f"tuned-plan logits [{backend}] differ at step {i}")
-        toks_by_backend[backend] = s_toks
+        toks_by_backend[backend] = got.tokens.tolist()
     assert toks_by_backend["gather"] == toks_by_backend["pallas"]
     return {"tokens": toks_by_backend["gather"],
             "knobs": sorted(loaded.knobs)}
@@ -321,8 +317,9 @@ def scenario_batcher(arch: str = "qwen3-0.6b"):
 
 
 def scenario_mesh_helpers():
-    """make_host_mesh validation + mesh_or_none degradation, with the
-    real 8-device topology visible."""
+    """make_host_mesh validation with the real 8-device topology visible:
+    a mesh the devices cannot hold raises (serving never degrades to one
+    device), and every axis is GSPMD-auto."""
     n = len(jax.devices())
     assert n == 8, f"worker expected 8 forced host devices, got {n}"
     m = make_host_mesh(4, 2)
@@ -341,9 +338,9 @@ def scenario_mesh_helpers():
             assert ">= 1" in str(e)
         else:
             raise AssertionError(f"make_host_mesh{bad} should have raised")
-    assert mesh_or_none(1, 1) is None
-    assert mesh_or_none(16, 1) is None
-    assert dict(mesh_or_none(2, 2).shape) == {"data": 2, "model": 2}
+    from jax.sharding import AxisType
+
+    assert set(m.axis_types) == {AxisType.Auto}, m.axis_types
     return {"devices": n}
 
 

@@ -21,6 +21,6 @@ def test_continuous_batcher_under_mesh(mesh_run):
 
 def test_host_mesh_validation(mesh_run):
     """make_host_mesh rejects oversubscribed / degenerate shapes with an
-    actionable error; mesh_or_none degrades to None instead."""
+    actionable error and builds GSPMD-auto axes."""
     out = mesh_run("mesh_helpers")
     assert out["devices"] == 8

@@ -188,3 +188,20 @@ def test_parallel_workers_identical_and_deterministic():
         _assert_equivalent(pa, pb)
     assert [t.name for t in rep_a.tables] == [s.name for s in specs]
     assert [t.cost for t in rep_a.tables] == [t.cost for t in rep_b.tables]
+
+
+def test_engine_pool_workers_stay_off_jax():
+    """Spawned compression workers import ``repro.core.engine`` afresh; it
+    must not pull in JAX, or a worker could claim the accelerator that
+    the serving process holds."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    code = ("import sys, repro.core.engine; "
+            "sys.exit(1 if 'jax' in sys.modules else 0)")
+    proc = subprocess.run([sys.executable, "-c", code], timeout=120,
+                          env=dict(os.environ, PYTHONPATH=src))
+    assert proc.returncode == 0

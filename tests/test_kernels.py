@@ -202,3 +202,33 @@ def test_wkv_kernel_matches_jnp_chunked():
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
                                rtol=1e-5, atol=1e-5)
+
+
+# --------------------------------------------------------------------------
+# lane_take: the one in-kernel table lookup (lane-row gather)
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("n,rows,cols", [
+    (1024, 8, 128),     # 10-bit table, one lane chunk
+    (300, 8, 384),      # ragged table, three lane chunks
+    (128, 1, 128),      # one row of indices
+    (77, 3, 40),        # narrow chunk (interpreter only on the TPU side)
+])
+def test_lane_take_equals_flat_take(n, rows, cols):
+    from repro.kernels.packing import lane_rows, lane_take
+
+    rng = np.random.default_rng(n + rows)
+    table = jnp.asarray(rng.integers(-2**31, 2**31 - 1, n), jnp.int32)
+    idx = jnp.asarray(rng.integers(0, n, (rows, cols)), jnp.int32)
+    got = lane_take(lane_rows(table), idx)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(jnp.take(table, idx)))
+
+
+def test_lane_rows_pads_to_whole_rows():
+    from repro.kernels.packing import LANES, lane_rows
+
+    a = jnp.arange(2 * 300, dtype=jnp.int32).reshape(2, 300)
+    r = lane_rows(a)
+    assert r.shape == (2, 3, LANES)
+    np.testing.assert_array_equal(np.asarray(r).reshape(2, -1)[:, :300], a)
+    assert not np.asarray(r).reshape(2, -1)[:, 300:].any()

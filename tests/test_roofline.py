@@ -103,8 +103,9 @@ def test_real_compile_roundtrip():
     import sys; sys.path.insert(0, "src")
     import jax, numpy as np, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_host_mesh
     from repro.roofline.hlo_costs import analyze_hlo
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(2, 4)
 
     def f(x, w):
         def body(h, wl):
