@@ -143,6 +143,7 @@ def fused_matmul_lut_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n_out), x.dtype),
         interpret=interpret,
+        name="lut_fused_matmul",
     )(layer, x, *([w] * len(w_specs)), *tabs, meta_i, meta_f)
 
 

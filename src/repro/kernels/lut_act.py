@@ -133,6 +133,7 @@ def lut_act_pallas(
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), x.dtype),
         interpret=interpret,
+        name="lut_act",
     )(x, *tabs)
 
 
@@ -232,6 +233,7 @@ def lut_act_stacked_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, lanes), x.dtype),
         interpret=interpret,
+        name="lut_act_stacked",
     )(layer, x, *tabs, meta_i, meta_f)
 
 
@@ -346,4 +348,5 @@ def lut_act_multisite_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, lanes), x.dtype),
         interpret=interpret,
+        name="lut_act_multisite",
     )(block_sites, layer, x, *tabs, meta_i, meta_f, meta_q, meta_p)

@@ -73,6 +73,7 @@ def lut_reconstruct_pallas(
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
         interpret=interpret,
+        name="lut_reconstruct",
     )(x, t_ust, t_idx, t_rsh, t_bias, t_lb)
 
 
@@ -101,4 +102,5 @@ def plain_lookup_pallas(
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
         interpret=interpret,
+        name="lut_plain_lookup",
     )(x, table)

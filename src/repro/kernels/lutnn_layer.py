@@ -63,4 +63,5 @@ def lutnn_layer_pallas(
         out_specs=pl.BlockSpec((block_b, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.int32),
         interpret=interpret,
+        name="lutnn_layer",
     )(codes, conn, tables)

@@ -40,13 +40,6 @@ def _fault_point(point: str) -> None:
     faults = sys.modules.get("repro.serve.faults")
     if faults is not None and faults._ACTIVE:
         faults.fault_point(point)
-    # Telemetry rides the same hook sites: per-backend kernel launch
-    # counters (trace-time wrapper invocations — see
-    # repro.obs.telemetry.kernel_launch for the exact semantics),
-    # resolved lazily so the kernels package never imports obs.
-    obs = sys.modules.get("repro.obs.telemetry")
-    if obs is not None and obs._STACK:
-        obs.kernel_launch(point)
 
 
 def _pad_to(a: np.ndarray, mult: int) -> np.ndarray:

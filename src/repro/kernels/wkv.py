@@ -94,4 +94,5 @@ def wkv_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
+        name="wkv",
     )(q, k, v, log_w, u)

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from repro import sites
 from repro.calib import capture as calib_capture
 from repro.obs import drift as obs_drift
-from repro.obs import telemetry as obs_telemetry
 
 from .layers import activation_fn, is_gated, logits_projection
 from .sharding import layer_scan, shard
@@ -270,12 +269,6 @@ def apply_lut_act(x, tab: dict, backend: str = "gather"):
     identical outputs by the bit-identity contract.
     """
     backend = tab.get("backend", backend)
-    if backend != "pallas" and obs_telemetry.telemetry_active():
-        # Pallas entries count in kernels/ops.py at the launch wrappers;
-        # the gather evaluators count here (same trace-time semantics).
-        obs_telemetry.kernel_launch(
-            "gather:lut_act_stacked" if "stacked" in tab
-            else "gather:lut_act")
     if "multi_entry" in tab:
         if backend != "pallas":
             raise ValueError(

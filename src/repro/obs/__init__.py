@@ -16,7 +16,9 @@ retune loop consumes):
   print through.
 
 Everything is off by default: no context entered means one ``None``
-check per host hook and zero traced ops in jitted steps.
+check per host hook and zero traced ops in jitted steps; ``obs.span``
+still enters a ``repro:<name>`` profiler annotation, which only a
+running profiler records.
 """
 from .drift import DontCareMonitor, monitor_active, suppressed
 from .events import OBS_SCHEMA, EventLog, read_events, record_crc
@@ -35,7 +37,6 @@ from .telemetry import (
     current,
     event,
     gauge,
-    kernel_launch,
     observe,
     span,
     telemetry_active,
@@ -62,7 +63,6 @@ __all__ = [
     "current",
     "event",
     "gauge",
-    "kernel_launch",
     "observe",
     "span",
     "telemetry_active",

@@ -15,6 +15,11 @@ Record shape::
     {"seq": N, "t": seconds-since-start, "event": "...",
      ["span": enclosing-span-id,] ...fields..., "crc": CRC32}
 
+``t`` and a span's ``dur_s`` come from the monotonic
+``time.perf_counter`` clock, so a wall-clock step cannot make a span
+negative; the ``obs_start`` header's ``wall_time`` anchors ``t = 0`` to
+the wall clock.
+
 Spans (:meth:`EventLog.span`) emit paired ``span_begin``/``span_end``
 records sharing a ``span_id``; nesting is recorded via ``parent`` on
 ``span_begin`` and the ``span`` field stamped on every record emitted
@@ -54,7 +59,7 @@ class EventLog:
         self.sample = max(1, int(sample))
         self.records: list[dict] = []
         self._seq = 0
-        self._t0 = time.time()
+        self._t0 = time.perf_counter()
         self._spans: list[str] = []       # open span ids, innermost last
         self._span_n = 0
         self._seen: dict[str, int] = {}     # sampled event -> occurrences
@@ -62,11 +67,12 @@ class EventLog:
         self._fh = open(path, "a", encoding="utf-8") if path else None
         self._closed = False
         self._write({"event": "obs_start", "schema": OBS_SCHEMA,
-                     "wall_time": round(self._t0, 3)})
+                     "wall_time": round(time.time(), 3)})
 
     # -- write path ---------------------------------------------------------
     def _write(self, rec: dict) -> dict:
-        rec = {"seq": self._seq, "t": round(time.time() - self._t0, 6),
+        rec = {"seq": self._seq,
+               "t": round(time.perf_counter() - self._t0, 6),
                **rec}
         # Round-trip through JSON first so the CRC is computed on exactly
         # the value a reader will parse back (non-JSON field values are
@@ -109,7 +115,7 @@ class EventLog:
         sid = f"s{self._span_n}"
         self._span_n += 1
         parent = self._spans[-1] if self._spans else None
-        t0 = time.time()
+        t0 = time.perf_counter()
         self.emit("span_begin", span_id=sid,
                   **({"parent": parent} if parent else {}),
                   name=name, **fields)
@@ -119,7 +125,7 @@ class EventLog:
         finally:
             self._spans.pop()
             self.emit("span_end", span_id=sid, name=name,
-                      dur_s=round(time.time() - t0, 6))
+                      dur_s=round(time.perf_counter() - t0, 6))
 
     def close(self, **fields) -> None:
         """Write the ``obs_end`` footer (record count + final payload,

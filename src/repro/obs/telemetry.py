@@ -8,6 +8,14 @@ does nothing when there is none — off-by-default telemetry costs one
 steps (the don't-care monitor's callbacks only exist while its context
 is entered, asserted in tests/test_obs.py).
 
+:func:`span` is the one exception to "does nothing": with or without a
+:class:`Telemetry`, it enters a ``jax.profiler.TraceAnnotation`` named
+``repro:<name>`` (its fields ride along as annotation metadata), so the
+program's spans land in a profiler trace on the device trace's clock.
+With no profiler running, that annotation is one cheap host call and is
+recorded nowhere.  "Off" therefore means: no ``EventLog`` records, no
+traced ops, and ``repro:`` annotations that only a profiler records.
+
 Entering a :class:`Telemetry` also enters its
 :class:`~repro.obs.drift.DontCareMonitor` (when attached); exiting
 flushes deferred callbacks, emits one ``drift`` event per observed site
@@ -18,7 +26,9 @@ footer, and optionally dumps the Prometheus text exposition to
 from __future__ import annotations
 
 import os
-from contextlib import nullcontext
+from contextlib import contextmanager, nullcontext
+
+from jax.profiler import TraceAnnotation
 
 from .drift import DontCareMonitor
 from .events import EventLog
@@ -116,11 +126,15 @@ def event(name: str, *, sampled: bool = False, **fields) -> None:
         t.event(name, sampled=sampled, **fields)
 
 
+@contextmanager
 def span(name: str, **fields):
-    t = current()
-    if t is not None:
-        return t.span(name, **fields)
-    return nullcontext()
+    """A ``repro:<name>`` profiler annotation, always; plus the active
+    telemetry's ``span_begin``/``span_end`` records when there is one.
+    Trace readers match on the name alone."""
+    with TraceAnnotation(f"repro:{name}", **fields):
+        t = current()
+        with t.span(name, **fields) if t else nullcontext() as sid:
+            yield sid
 
 
 def count(name: str, amount: float = 1.0, help: str = "", **labels) -> None:
@@ -139,21 +153,3 @@ def observe(name: str, value: float, help: str = "", **labels) -> None:
     t = current()
     if t is not None:
         t.registry.histogram(name, help).observe(value, **labels)
-
-
-def kernel_launch(point: str) -> None:
-    """Per-backend kernel launch counter (``"backend:kernel"`` points).
-
-    Counts trace-time wrapper invocations — one per compiled trace of a
-    step (and per scan when the evaluator sits outside it), not one per
-    executed device launch; a re-trace after a table swap counts again.
-    That is the observable XLA gives us without perturbing the program,
-    and it is exactly what the degradation ladder needs: which backend's
-    evaluators the served step was built from."""
-    t = current()
-    if t is not None:
-        backend, _, kern = point.partition(":")
-        t.registry.counter(
-            "kernel_launches_total",
-            "trace-time kernel wrapper invocations by backend",
-        ).inc(backend=backend, kernel=kern)

@@ -49,9 +49,10 @@ class Generation:
         return self.logits[1] if len(self.logits) > 1 else None
 
 
-def _compiled(lowered):
+def _compiled(lowered, program: str):
     t0 = time.perf_counter()
-    exe = lowered.compile()
+    with obs.span(f"compile.{program}"):
+        exe = lowered.compile()
     return exe, time.perf_counter() - t0
 
 
@@ -68,62 +69,76 @@ def generate(cfg, params, batch: dict, new_tokens: int, *,
     replay counts as prefill time.  ``max_seq`` defaults to the prompt
     (patch prefix included) plus ``new_tokens``.  ``all_logits`` keeps
     every decode step's logits, not only the first one's.
+
+    The host work is in ``repro.obs`` spans (profiler annotations named
+    ``repro:<span>``): ``generate`` around the call; ``lower.prefill``,
+    ``lower.decode`` (tracing and lowering each program) and
+    ``compile.prefill``, ``compile.decode`` (``Lowered.compile()``: a
+    compile, or a persistent-cache load); ``prefill``, ``decode``; and
+    ``readback`` (tokens and kept logits to the host).
     """
     tokens = batch["tokens"]
     b, t = tokens.shape
-    if cfg.family == "vlm" and "patches" in batch:
-        t += batch["patches"].shape[1]   # the patch prefix fills the cache
-    max_seq = max_seq or t + new_tokens
-    if serve is None:
-        extra = ()
-        pre_low = jax.jit(lambda p, x: prefill(
-            p, cfg, x, max_seq=max_seq, lut_tables=lut_tables)).lower(
-            params, batch)
-    else:
-        extra = (serve.table_operands,)
-        pre_low = serve.lower_prefill(params, batch, max_seq)
-    pre_exe, pre_compile_s = _compiled(pre_low)
-    t0 = time.perf_counter()
-    with obs.span("prefill", batch=b, prompt_len=t):
-        logits, cache = jax.block_until_ready(pre_exe(params, batch, *extra))
-        if kv_int8 and cfg.family in ("dense", "moe", "vlm"):
-            cache_q = init_cache(cfg, b, max_seq, kv_dtype="int8")
-            if serve is not None:
-                cache_q = serve.place_cache(cache_q)
-                logits, cache = serve.replay(params, cache_q, tokens)
+    with obs.span("generate", batch=b, prompt_len=t,
+                  new_tokens=new_tokens):
+        if cfg.family == "vlm" and "patches" in batch:
+            t += batch["patches"].shape[1]  # the patch prefix fills the cache
+        max_seq = max_seq or t + new_tokens
+        with obs.span("lower.prefill"):
+            if serve is None:
+                extra = ()
+                pre_low = jax.jit(lambda p, x: prefill(
+                    p, cfg, x, max_seq=max_seq,
+                    lut_tables=lut_tables)).lower(params, batch)
             else:
-                logits, cache = jax.jit(lambda p, c, tk: prefill_replay(
-                    p, cfg, c, tk, 0, lut_tables=lut_tables))(
-                    params, cache_q, tokens)
-            jax.block_until_ready(cache)
-    pre_s = time.perf_counter() - t0
-    kept = [logits[:, -1]]
+                extra = (serve.table_operands,)
+                pre_low = serve.lower_prefill(params, batch, max_seq)
+        pre_exe, pre_compile_s = _compiled(pre_low, "prefill")
+        t0 = time.perf_counter()
+        with obs.span("prefill", batch=b, prompt_len=t):
+            logits, cache = jax.block_until_ready(
+                pre_exe(params, batch, *extra))
+            if kv_int8 and cfg.family in ("dense", "moe", "vlm"):
+                cache_q = init_cache(cfg, b, max_seq, kv_dtype="int8")
+                if serve is not None:
+                    cache_q = serve.place_cache(cache_q)
+                    logits, cache = serve.replay(params, cache_q, tokens)
+                else:
+                    logits, cache = jax.jit(lambda p, c, tk: prefill_replay(
+                        p, cfg, c, tk, 0, lut_tables=lut_tables))(
+                        params, cache_q, tokens)
+                jax.block_until_ready(cache)
+        pre_s = time.perf_counter() - t0
+        kept = [logits[:, -1]]
 
-    tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-    pos0 = np.int32(t)
-    if serve is None:
-        dec_low = jax.jit(lambda p, c, tk, pos: decode_step(
-            p, cfg, c, tk, pos, lut_tables=lut_tables)).lower(
-            params, cache, tok, pos0)
-    else:
-        dec_low = serve.lower_decode(params, cache, tok, pos0)
-    dec_exe, dec_compile_s = _compiled(dec_low)
-    outs = []
-    t0 = time.perf_counter()
-    with obs.span("decode", batch=b, new_tokens=new_tokens):
-        for i in range(new_tokens):
-            outs.append(tok)
-            logits, cache = dec_exe(params, cache, tok, np.int32(t + i),
-                                    *extra)
-            tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-            if i == 0 or all_logits:
-                kept.append(logits[:, -1])
-        jax.block_until_ready((tok, cache))
-    dec_s = time.perf_counter() - t0
-    toks = (np.concatenate([np.asarray(o) for o in outs], axis=1)
-            if outs else np.zeros((b, 0), np.int32))
-    return Generation(
-        tokens=toks, logits=[np.asarray(lg, np.float32) for lg in kept],
-        prefill_compile_s=pre_compile_s, prefill_s=pre_s,
-        decode_compile_s=dec_compile_s, decode_s=dec_s,
-        decode_program=dec_exe)
+        tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+        pos0 = np.int32(t)
+        with obs.span("lower.decode"):
+            if serve is None:
+                dec_low = jax.jit(lambda p, c, tk, pos: decode_step(
+                    p, cfg, c, tk, pos, lut_tables=lut_tables)).lower(
+                    params, cache, tok, pos0)
+            else:
+                dec_low = serve.lower_decode(params, cache, tok, pos0)
+        dec_exe, dec_compile_s = _compiled(dec_low, "decode")
+        outs = []
+        t0 = time.perf_counter()
+        with obs.span("decode", batch=b, new_tokens=new_tokens):
+            for i in range(new_tokens):
+                outs.append(tok)
+                logits, cache = dec_exe(params, cache, tok, np.int32(t + i),
+                                        *extra)
+                tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+                if i == 0 or all_logits:
+                    kept.append(logits[:, -1])
+            jax.block_until_ready((tok, cache))
+        dec_s = time.perf_counter() - t0
+        with obs.span("readback"):
+            toks = (np.concatenate([np.asarray(o) for o in outs], axis=1)
+                    if outs else np.zeros((b, 0), np.int32))
+            kept = [np.asarray(lg, np.float32) for lg in kept]
+        return Generation(
+            tokens=toks, logits=kept,
+            prefill_compile_s=pre_compile_s, prefill_s=pre_s,
+            decode_compile_s=dec_compile_s, decode_s=dec_s,
+            decode_program=dec_exe)

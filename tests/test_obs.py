@@ -409,6 +409,89 @@ def test_disabled_telemetry_adds_zero_traced_ops(served_model):
         assert "callback" in lower()
 
 
+def test_disabled_telemetry_spans_add_no_records_or_traced_ops(
+        served_model):
+    """With no telemetry entered, ``obs.span`` writes no event record and
+    adds no op to the decode step, whether it wraps the lowering or sits
+    inside the traced function."""
+    cfg, params, plans, _ = served_model
+    tables = plans.tables_for_model(backend="pallas")
+    cache = jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(lambda p, x: prefill(p, cfg, x, max_seq=8,
+                                            lut_tables=tables),
+                       params, {"tokens": jnp.ones((2, 5), jnp.int32)})[1])
+    tok = jnp.zeros((2, 1), jnp.int32)
+
+    def step(p, c, tk, pos):
+        return decode_step(p, cfg, c, tk, pos, lut_tables=tables)
+
+    def spanned_step(p, c, tk, pos):
+        with obs.span("traced"):
+            return step(p, c, tk, pos)
+
+    def lower(fn):       # the program, without the module's name
+        return jax.jit(fn).lower(params, cache, tok, jnp.asarray(
+            5)).as_text().split("\n", 1)[1]
+
+    log = obs.EventLog()
+    obs.Telemetry(events=log)            # built, never entered
+    plain = lower(step)
+    with obs.span("lower.decode", batch=2):
+        around = lower(step)
+    assert obs.current() is None
+    assert around == plain == lower(spanned_step)
+    assert "callback" not in plain
+    assert [r["event"] for r in log.records] == ["obs_start"]
+
+
+def _host_event_names(tmp_path, fn) -> list:
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    return [ev.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_spans_reach_the_event_log_and_the_profile(tmp_path):
+    """With telemetry on, a span writes its ``span_begin``/``span_end``
+    records as before (monotonic ``t``, ``dur_s``) and, under the
+    profiler, a ``repro:<name>`` annotation too."""
+    tel = obs.Telemetry(events=obs.EventLog())
+
+    def work():
+        with tel:
+            with obs.span("outer", tag="t") as sid:
+                with obs.span("inner"):
+                    obs.event("step", n=1)
+        assert sid == "s0"
+
+    names = _host_event_names(tmp_path, work)
+    assert "repro:outer" in names and "repro:inner" in names
+    recs = tel.events.records
+    assert [r["event"] for r in recs] == [
+        "obs_start", "span_begin", "span_begin", "step", "span_end",
+        "span_end", "obs_end"]
+    outer, inner = recs[1], recs[2]
+    assert set(outer) == {"seq", "t", "event", "span_id", "name", "tag",
+                          "crc"}
+    assert (outer["name"], outer["tag"], outer["span_id"]) == (
+        "outer", "t", "s0")
+    assert inner["parent"] == "s0" and recs[3]["span"] == inner["span_id"]
+    assert [r["span_id"] for r in recs[4:6]] == [inner["span_id"], "s0"]
+    assert all(r["dur_s"] >= 0 for r in recs[4:6])
+    ts = [r["t"] for r in recs]
+    assert ts == sorted(ts)
+    assert isinstance(recs[0]["wall_time"], float)
+
+
 def test_event_log_records_metrics_footer(tmp_path):
     """Telemetry.finish lands the metrics snapshot in the footer and the
     Prometheus dump on disk, on every exit path."""

@@ -97,8 +97,13 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _tpu_kernel(compiled) -> bool:
-    return "tpu_custom_call" in compiled.as_text()
+def _tpu_kernel(compiled, name: str) -> bool:
+    """A Mosaic kernel is in the program, and its HLO instruction carries
+    the ``pallas_call``'s ``name``: the device trace's ``XLA Ops`` event
+    for the kernel is that instruction, so the trace names it."""
+    return any(line.strip().removeprefix("ROOT ").startswith(f"%{name}.")
+               and 'custom_call_target="tpu_custom_call"' in line
+               for line in compiled.as_text().splitlines())
 
 
 def test_lut_act_pallas_compiles(one_chip, compiled_kernels):
@@ -114,7 +119,7 @@ def test_lut_act_pallas_compiles(one_chip, compiled_kernels):
         y_hi=m["y_hi"], pack=pa.pack)
     args = [_spec((ROWS, D_FF), jnp.bfloat16, one_chip)] + [
         _spec(t.shape, t.dtype, one_chip) for t in tabs]
-    assert _tpu_kernel(_compile(fn, *args))
+    assert _tpu_kernel(_compile(fn, *args), "lut_act")
 
 
 @pytest.mark.parametrize("packed", [False, True], ids=["raw", "packed"])
@@ -132,7 +137,7 @@ def test_lut_act_stacked_pallas_compiles(one_chip, compiled_kernels, stack,
              _spec((1,), jnp.int32, one_chip)]
             + [_spec(t.shape, t.dtype, one_chip)
                for t in tabs + [e["meta_i"], e["meta_f"]]])
-    assert _tpu_kernel(_compile(fn, *args))
+    assert _tpu_kernel(_compile(fn, *args), "lut_act_stacked")
 
 
 def test_lut_act_multisite_pallas_compiles(one_chip, compiled_kernels, stack):
@@ -147,7 +152,7 @@ def test_lut_act_multisite_pallas_compiles(one_chip, compiled_kernels, stack):
     args = ([x, _spec((n_blocks,), jnp.int32, one_chip),
              _spec((1,), jnp.int32, one_chip)]
             + [_spec(t.shape, t.dtype, one_chip) for t in tabs + metas])
-    assert _tpu_kernel(_compile(fn, *args))
+    assert _tpu_kernel(_compile(fn, *args), "lut_act_multisite")
 
 
 def test_fused_matmul_lut_pallas_compiles(one_chip, compiled_kernels, stack):
@@ -166,13 +171,14 @@ def test_fused_matmul_lut_pallas_compiles(one_chip, compiled_kernels, stack):
              _spec((1,), jnp.int32, one_chip)]
             + [_spec(t.shape, t.dtype, one_chip)
                for t in tabs + [e["meta_i"], e["meta_f"]]])
-    assert _tpu_kernel(_compile(fn, *args))
+    assert _tpu_kernel(_compile(fn, *args), "lut_fused_matmul")
 
 
 def test_full_width_decode_step_with_pallas_tables_compiles(
         one_chip, compiled_kernels, stack):
     """qwen3-0.6b's whole decode step, batch 8 against a 1024-deep cache,
-    with the stacked Pallas MLP tables: the kernel is in the program."""
+    with the stacked Pallas MLP tables: the kernel is in the program, and
+    keeps its name once inlined into the served step."""
     import dataclasses
 
     cfg = dataclasses.replace(get_config("qwen3-0.6b"), lut_activation=True)
@@ -189,6 +195,6 @@ def test_full_width_decode_step_with_pallas_tables_compiles(
     compiled = _compile(
         lambda p, c, t, i: decode_step(p, cfg, c, t, i, lut_tables=tables),
         params, cache, tok, pos)
-    assert _tpu_kernel(compiled)
+    assert _tpu_kernel(compiled, "lut_act_stacked")
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 16e9   # fits one v5e chip's HBM
