@@ -4,10 +4,16 @@ The one serving loop that ``repro.launch.serve``, the backend-equivalence
 harness (:func:`repro.serve.plans.verify_backend_equivalence`) and
 ``chip_smoke.py`` share, single-device or through a
 :class:`~repro.serve.sharded.ShardedServe`.
+
+The compiled programs are kept across calls, in a bounded process-wide
+cache (:func:`clear_programs` empties it): a call whose programs were
+built before runs them with no tracing, lowering or compile-cache load.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import threading
 import time
 
 import jax
@@ -15,9 +21,79 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.calib.capture import capture_active
+from repro.obs.drift import monitor_active
 
 from .decode import decode_step, prefill, prefill_replay
+from .faults import injection_active
 from .kvcache import init_cache
+
+MAX_PROGRAMS = 8
+
+
+class _Programs:
+    """Compiled programs by key, least recently used evicted first.
+
+    Each entry holds its executable and the tables or ``ShardedServe``
+    object whose ``id`` is in its key, so that ``id`` cannot name
+    another object while the entry lives; never weights, a batch or a
+    cache.
+    """
+
+    def __init__(self, bound: int):
+        self.bound = bound
+        self._entries: collections.OrderedDict = collections.OrderedDict()
+        self._lock = threading.Lock()
+
+    def get(self, key):
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is None:
+                return None
+            self._entries.move_to_end(key)
+            return entry[0]
+
+    def put(self, key, exe, owner) -> None:
+        with self._lock:
+            self._entries[key] = (exe, owner)
+            self._entries.move_to_end(key)
+            while len(self._entries) > self.bound:
+                self._entries.popitem(last=False)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._entries.clear()
+
+    def __contains__(self, key) -> bool:
+        return key in self._entries
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+
+_PROGRAMS = _Programs(MAX_PROGRAMS)
+
+
+def clear_programs() -> None:
+    """Drop every kept program: the next call of each shape builds anew.
+    For callers that change a tables dict in place or flip a module
+    switch of :mod:`repro.nn`."""
+    _PROGRAMS.clear()
+
+
+def _hook_entered() -> bool:
+    """True while a trace-time hook is entered (an activation capture, a
+    don't-care monitor, a fault injector): a program traced then calls
+    into the hook, so it is built for that call alone and not kept."""
+    return capture_active() or monitor_active() or injection_active()
+
+
+def _signature(*args) -> tuple:
+    """Tree structure, and type (shape, dtype, weak type) and placement
+    of every leaf: what a compiled program is specialised to."""
+    leaves, tree = jax.tree.flatten(args)
+    return tree, tuple((jax.typeof(x), getattr(x, "sharding", None))
+                       for x in leaves)
 
 
 @dataclasses.dataclass
@@ -25,7 +101,8 @@ class Generation:
     """One greedy prefill + decode run and where its time went.
 
     Compile and run times are separate: each program is compiled ahead of
-    time (``lower().compile()``), and every run time ends in
+    time (``lower().compile()``), or taken from the kept programs (the
+    compile times are then the lookup's), and every run time ends in
     ``block_until_ready``.
     """
 
@@ -49,11 +126,28 @@ class Generation:
         return self.logits[1] if len(self.logits) > 1 else None
 
 
-def _compiled(lowered, program: str):
+def _program(program: str, key, owner, lower):
+    """The compiled ``program`` kept under ``key``, or ``lower()``
+    compiled now and kept (``key`` None: for this call alone).  Returns
+    the executable and its compile seconds: the compile's, or on a hit
+    the lookup's (both spans)."""
+    hit = key is not None and key in _PROGRAMS
+    obs.count("generate_programs_total", program=program,
+              outcome="hit" if hit else "miss")
+    cached = {"cached": True} if hit else {}
     t0 = time.perf_counter()
-    with obs.span(f"compile.{program}"):
-        exe = lowered.compile()
-    return exe, time.perf_counter() - t0
+    with obs.span(f"lower.{program}", **cached):
+        exe = _PROGRAMS.get(key) if hit else None
+        lowered = lower() if exe is None else None
+    if lowered is not None:
+        t0 = time.perf_counter()
+    with obs.span(f"compile.{program}", **cached):
+        if lowered is not None:
+            exe = lowered.compile()
+    compile_s = time.perf_counter() - t0
+    if lowered is not None and key is not None:
+        _PROGRAMS.put(key, exe, owner)
+    return exe, compile_s
 
 
 def generate(cfg, params, batch: dict, new_tokens: int, *,
@@ -70,12 +164,25 @@ def generate(cfg, params, batch: dict, new_tokens: int, *,
     (patch prefix included) plus ``new_tokens``.  ``all_logits`` keeps
     every decode step's logits, not only the first one's.
 
+    The compiled programs (prefill, decode, and the ``kv_int8`` replay)
+    are kept across calls, each under its program and traced function,
+    ``cfg``, ``max_seq``, the identity of ``lut_tables`` (or of
+    ``serve``) and the arguments' types and placements; nothing is kept
+    while a trace-time hook is entered (:func:`_hook_entered`).  A call
+    that matches runs the kept executables; counted as
+    ``generate_programs_total`` (``program``, ``outcome`` ``hit`` or
+    ``miss``) under an entered ``Telemetry``.  The module switches of
+    :mod:`repro.nn` (``FAST_STREAM``, ``SEQ_PARALLEL``, ``WKV_CHUNK``)
+    are not in the key: a caller that flips one between calls clears
+    the kept programs first (:func:`clear_programs`).
+
     The host work is in ``repro.obs`` spans (profiler annotations named
     ``repro:<span>``): ``generate`` around the call; ``lower.prefill``,
     ``lower.decode`` (tracing and lowering each program) and
     ``compile.prefill``, ``compile.decode`` (``Lowered.compile()``: a
-    compile, or a persistent-cache load); ``prefill``, ``decode``; and
-    ``readback`` (tokens and kept logits to the host).
+    compile, or a persistent-cache load), both only a lookup and marked
+    ``cached=True`` where the program was kept; ``prefill``, ``decode``;
+    and ``readback`` (tokens and kept logits to the host).
     """
     tokens = batch["tokens"]
     b, t = tokens.shape
@@ -84,16 +191,25 @@ def generate(cfg, params, batch: dict, new_tokens: int, *,
         if cfg.family == "vlm" and "patches" in batch:
             t += batch["patches"].shape[1]  # the patch prefix fills the cache
         max_seq = max_seq or t + new_tokens
-        with obs.span("lower.prefill"):
-            if serve is None:
-                extra = ()
-                pre_low = jax.jit(lambda p, x: prefill(
-                    p, cfg, x, max_seq=max_seq,
-                    lut_tables=lut_tables)).lower(params, batch)
-            else:
-                extra = (serve.table_operands,)
-                pre_low = serve.lower_prefill(params, batch, max_seq)
-        pre_exe, pre_compile_s = _compiled(pre_low, "prefill")
+        keep = not _hook_entered()
+        owner = lut_tables if serve is None else serve
+
+        def key(program, fn, *args):
+            if not keep:
+                return None
+            return (program, fn, cfg, max_seq, id(owner), _signature(*args))
+
+        def lower_prefill():
+            if serve is not None:
+                return serve.lower_prefill(params, batch, max_seq)
+            return jax.jit(lambda p, x: prefill(
+                p, cfg, x, max_seq=max_seq,
+                lut_tables=lut_tables)).lower(params, batch)
+
+        extra = () if serve is None else (serve.table_operands,)
+        pre_exe, pre_compile_s = _program(
+            "prefill", key("prefill", prefill, params, batch), owner,
+            lower_prefill)
         t0 = time.perf_counter()
         with obs.span("prefill", batch=b, prompt_len=t):
             logits, cache = jax.block_until_ready(
@@ -104,23 +220,33 @@ def generate(cfg, params, batch: dict, new_tokens: int, *,
                     cache_q = serve.place_cache(cache_q)
                     logits, cache = serve.replay(params, cache_q, tokens)
                 else:
-                    logits, cache = jax.jit(lambda p, c, tk: prefill_replay(
-                        p, cfg, c, tk, 0, lut_tables=lut_tables))(
-                        params, cache_q, tokens)
+                    def lower_replay():
+                        return jax.jit(lambda p, c, tk: prefill_replay(
+                            p, cfg, c, tk, 0, lut_tables=lut_tables)).lower(
+                            params, cache_q, tokens)
+
+                    rep_exe, _ = _program(
+                        "replay",
+                        key("replay", prefill_replay, params, cache_q, tokens),
+                        owner, lower_replay)
+                    logits, cache = rep_exe(params, cache_q, tokens)
                 jax.block_until_ready(cache)
         pre_s = time.perf_counter() - t0
         kept = [logits[:, -1]]
 
         tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
         pos0 = np.int32(t)
-        with obs.span("lower.decode"):
-            if serve is None:
-                dec_low = jax.jit(lambda p, c, tk, pos: decode_step(
-                    p, cfg, c, tk, pos, lut_tables=lut_tables)).lower(
-                    params, cache, tok, pos0)
-            else:
-                dec_low = serve.lower_decode(params, cache, tok, pos0)
-        dec_exe, dec_compile_s = _compiled(dec_low, "decode")
+
+        def lower_decode():
+            if serve is not None:
+                return serve.lower_decode(params, cache, tok, pos0)
+            return jax.jit(lambda p, c, tk, pos: decode_step(
+                p, cfg, c, tk, pos, lut_tables=lut_tables)).lower(
+                params, cache, tok, pos0)
+
+        dec_exe, dec_compile_s = _program(
+            "decode", key("decode", decode_step, params, cache, tok, pos0),
+            owner, lower_decode)
         outs = []
         t0 = time.perf_counter()
         with obs.span("decode", batch=b, new_tokens=new_tokens):

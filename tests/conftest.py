@@ -15,6 +15,8 @@ import sys
 import types
 import zlib
 
+import pytest
+
 
 def _install_hypothesis_stub() -> None:
     try:
@@ -120,3 +122,13 @@ def _install_hypothesis_stub() -> None:
 
 
 _install_hypothesis_stub()
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_kept_programs():
+    """``repro.serve.generate`` keeps compiled programs process-wide:
+    each test module starts without them, so its first call of a shape
+    builds its programs whatever ran before in the same process."""
+    generate = sys.modules.get("repro.serve.generate")
+    if generate is not None:
+        generate.clear_programs()
