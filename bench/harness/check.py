@@ -7,6 +7,10 @@ on the served tokens.  For each served token, its *gap* is how far the
 reference's logit for it lies below the reference's best logit at that
 position.  The number compared is the widest gap over the sample; its
 limit is in ``limits/<cell>.json``, with the readings it was set from.
+A limits file names each number it holds a cell to, so a cell whose
+widest gap cannot separate sound runs from the control (a router's
+near-ties flip an expert) is held to another of :func:`compare`'s
+numbers by its own file.
 
 The control (``--readings`` only) puts the reference computed with fp8
 projections in the program's place: at the same positions of the same
@@ -26,13 +30,24 @@ from . import model, traffic
 NUMBERS = ("max_logit_gap",)
 
 
-def reference_weights(m: dict, seed: int):
+def reference_weights(arch, m: dict, seed: int):
     """``(global_fn, layer_fn)`` drawing the run's weights from the seed
-    again, layer by layer, with the benchmark's own generator."""
+    again, layer by layer, with the architecture module's own generator.
+    ``layer_fn(i)`` tells the generator the layer as a Python int; the
+    layers whose draw traces to the same program share one compiled
+    draw, so a stack of like layers compiles once."""
     glob, layers = model.seed_keys(seed, m["L"])
-    g = jax.jit(functools.partial(model.global_weights, m))
-    lw = jax.jit(functools.partial(model.layer_weights, m))
-    return (lambda: g(glob)), (lambda i: lw(layers[i]))
+    g = jax.jit(functools.partial(arch.global_weights, m))
+    draws = {}
+
+    def layer_fn(i):
+        draw = functools.partial(arch.layer_weights, m, layer=i)
+        program = str(jax.make_jaxpr(draw)(layers[i]))
+        if program not in draws:
+            draws[program] = jax.jit(draw)
+        return draws[program](layers[i])
+
+    return (lambda: g(glob)), layer_fn
 
 
 def gaps(ref: np.ndarray, served: np.ndarray) -> np.ndarray:
@@ -92,14 +107,16 @@ def open_loop_sample(served_list, mix: dict, seed: int):
     return toks, pos, served, valid
 
 
-def compare(conf: dict, seed: int, sample, *, control: bool = False
-            ) -> dict:
-    """The numbers compared, for the program (and the control)."""
+def compare(conf: dict, bench: Path, seed: int, sample, *,
+            control: bool = False) -> dict:
+    """The numbers compared, for the program (and the control); the
+    architecture and reference modules come from ``bench``."""
     tokens, positions, served = sample[:3]
     valid = sample[3] if len(sample) > 3 else np.ones(served.shape, bool)
-    m = model.dims(conf)
-    ref_mod = model.load_reference(conf["reference"])
-    w = reference_weights(m, seed)
+    arch = model.load_architecture(conf, bench)
+    m = arch.dims(conf)
+    ref_mod = model.load_reference(conf["reference"], bench)
+    w = reference_weights(arch, m, seed)
     ref = ref_mod.logits_at(m, w, tokens, positions)
     g = gaps(ref, served)[valid]
     out = {"max_logit_gap": float(g.max()),
@@ -123,11 +140,13 @@ def load_limits(bench: Path, cell: str) -> dict | None:
 
 
 def judge(numbers: dict, limits: dict | None) -> tuple[bool, dict]:
-    """``(correct, {name: {"value", "limit"}})``; no limits, not correct."""
+    """``(correct, {name: {"value", "limit"}})`` for each number the
+    limits file names (:data:`NUMBERS` where there is no file); no
+    limits, or a number missing, is not correct."""
     checks = {}
-    ok = limits is not None
-    for name in NUMBERS:
-        lim = None if limits is None else limits[name]["limit"]
+    ok = bool(limits)
+    for name in limits or NUMBERS:
+        lim = limits[name]["limit"] if limits else None
         val = numbers.get(name)
         checks[name] = {"value": val, "limit": lim}
         ok = ok and val is not None and lim is not None and val <= lim

@@ -15,16 +15,21 @@ from pathlib import Path
 @dataclasses.dataclass
 class Run:
     kind: str                   # the traffic's kind: offline | open_loop
-    m: dict                     # harness.model.dims of the configuration
+    arch: object                # the configuration's architecture module
+    m: dict                     # arch.dims of the configuration
     peaks: dict                 # harness.device.peaks of the chip
     mix: dict                   # the traffic file
     setup_s: float
     calib_s: float
-    table_bytes: float          # served MLP tables of one layer
+    site_bytes: dict            # served tables of one hosting layer, by
+                                # site (harness.system.site_bytes)
     window_s: float
     calls: list | None = None   # offline: harness.loops.Call
     loop: object = None         # open loop: harness.loops.OpenLoop
     trace: object = None        # harness.trace.Reduced of a traced run
+    # the program's repro.obs counters at the window's close:
+    # {metric: {labels: value}} (MetricsRegistry.snapshot)
+    counters: dict = dataclasses.field(default_factory=dict)
 
 
 def load(bench: Path, name: str):

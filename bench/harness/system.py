@@ -20,13 +20,37 @@ def span(name: str):
     return TraceAnnotation(f"bench:{name}")
 
 
+def _nbytes(arrays) -> int:
+    return sum(int(np.asarray(a).nbytes) for a in jax.tree.leaves(arrays))
+
+
+def site_bytes(tables: dict) -> dict:
+    """``{site: served table bytes per hosting layer}`` of every site in
+    ``tables["sites"]``: a stacked entry's arrays over its ``n_layers``,
+    an unrolled entry's over its layers, a shared table whole."""
+    out = {}
+    for key, entry in tables["sites"].items():
+        if "stacked" in entry:
+            st = entry["stacked"]
+            out[key] = _nbytes(st["arrays"]) / st["meta"]["n_layers"]
+        elif "layers" in entry:
+            layers = entry["layers"]
+            out[key] = sum(_nbytes(x["arrays"]) for x in layers) / len(layers)
+        elif "arrays" in entry:
+            out[key] = float(_nbytes(entry["arrays"]))
+        else:
+            raise ValueError(f"site {key}: no table bytes in an entry of "
+                             f"keys {sorted(entry)}")
+    return out
+
+
 def calibrate(cfg, params, batch: dict, *, backend: str = "pallas",
               plan_exec: str = "stacked"):
     """Capture one calibration batch through the exact model and compress
-    every layer's MLP activation table.  Returns ``(lut_cfg, tables,
-    table_bytes, seconds)``; ``table_bytes`` is the served MLP tables'
-    size for one layer."""
-    from repro import sites
+    every layer's table at each site the configuration hosts.  Returns
+    ``(lut_cfg, tables, site_bytes, seconds)``; ``site_bytes`` maps each
+    served site to its tables' size for one hosting layer
+    (:func:`site_bytes`)."""
     from repro.calib import (ActivationCapture, calibration_from_capture,
                              capture_model)
     from repro.serve import build_serving_plans
@@ -41,11 +65,7 @@ def calibrate(cfg, params, batch: dict, *, backend: str = "pallas",
         tables = plans.tables_for_model(backend=backend)
         jax.block_until_ready(tables)
     secs = time.perf_counter() - t0
-    site = tables["sites"][sites.MLP]
-    entry = site.get("stacked", site)
-    nbytes = sum(int(np.asarray(a).nbytes)
-                 for a in jax.tree.leaves(entry.get("arrays", entry)))
-    return plans.patched_config(cfg), tables, nbytes / cfg.n_layers, secs
+    return plans.patched_config(cfg), tables, site_bytes(tables), secs
 
 
 def generate(lut_cfg, params, tables, batch: dict, new_tokens: int):

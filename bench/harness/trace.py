@@ -96,6 +96,13 @@ class Reduced:
         return out
 
 
+def is_lut(op) -> bool:
+    """A Pallas LUT kernel: each passes a ``name`` that starts with
+    ``lut_``, which is its HLO instruction's name in the ``XLA Ops``
+    events (``lut_act_stacked.3:tpu_custom_call``)."""
+    return short_name(op.name).startswith("lut_")
+
+
 def short_name(hlo: str) -> str:
     """``%fusion.12 = bf16[..] fusion(..), ...`` -> ``fusion.12``, with the
     custom-call target where there is one."""

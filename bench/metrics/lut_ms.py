@@ -1,12 +1,8 @@
 """LUT kernel device time per call: the device time of the ops whose
 name starts with ``lut_`` (the Pallas LUT kernels' ``name``, which is
-their HLO instruction's name in the ``XLA Ops`` events), over the
-window's calls (device trace)."""
-from harness import trace
-
-
-def is_lut(op) -> bool:
-    return trace.short_name(op.name).startswith("lut_")
+their HLO instruction's name in the ``XLA Ops`` events;
+``harness.trace.is_lut``), over the window's calls (device trace)."""
+from harness.trace import is_lut
 
 
 def read(run):

@@ -1,27 +1,21 @@
-"""The LUT kernel's share of its roofline: the least time the MLP LUT
-site's work could take on this chip, over the device time of the LUT
+"""The LUT kernels' share of their roofline: the least time the LUT
+sites' work could take on this chip, over the device time of the LUT
 kernels in the trace.
 
-The work is counted from the site's logical shapes (``harness.work``):
-every activation element read and written in bf16, plus one layer's
-served table bytes per layer call.  A lookup does no MXU work, so the
-bound is HBM bandwidth.
+The work is counted by the configuration's architecture module from
+the sites' logical shapes (``run.arch.generate_lut``): every activation
+element read and written in bf16, plus each hosting layer's served
+table bytes (``run.site_bytes``) per layer call.  A lookup does no MXU
+work, so the bound is HBM bandwidth.
 
-The kernels are found by the strings below in their ``XLA Ops`` events.
-The program's ``pallas_call``s pass no ``name=``, so a Mosaic kernel
-shows only as a ``tpu_custom_call`` (with ``kernel_metadata={}``); on the
-served path of these cells the Pallas LUT lookups are the only Mosaic
-kernels.  Once the kernels carry names, add them here.
+The kernels are the ops whose name starts with ``lut_``: each Pallas
+LUT kernel passes that ``name``, which is its HLO instruction's name in
+the ``XLA Ops`` events (``lut_act_stacked.3:tpu_custom_call``), the
+match ``lut_ms`` reads (``harness.trace.is_lut``).
 """
-from harness import work
+from harness.trace import is_lut
 
-KERNELS = ('custom_call_target="tpu_custom_call"',)
 BOUND = "hbm"
-
-
-def is_lut(op) -> bool:
-    return any(k in op.meta for k in KERNELS)
-
 
 def read(run):
     if run.kind != "offline" or run.trace is None or not run.calls:
@@ -30,7 +24,7 @@ def read(run):
     if secs <= 0:
         return None
     mix = run.mix
-    nbytes = len(run.calls) * work.generate_lut(
+    nbytes = len(run.calls) * run.arch.generate_lut(
         run.m, mix["batch"], mix["prompt_len"], mix["new_tokens"],
-        run.table_bytes)
+        run.site_bytes)
     return 100.0 * (nbytes / run.peaks["hbm_bytes_per_s"]) / secs

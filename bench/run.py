@@ -5,14 +5,17 @@
 
 The cell (``BENCHMARK.json``'s ``workloads``) names a configuration
 (``bench/configs/<name>.json``) and a traffic mix
-(``bench/traffic/<name>.json``); the metrics are read by
-``bench/metrics/<name>.py`` and the correctness limits sit in
-``bench/limits/<cell>.json``.  Set-up makes the weights from the seed,
-calibrates and compresses the LUT tables, and warms every shape the
-window uses; then the window runs for ``--seconds``
-(``--trace 1``: at most 15 s, under the profiler).  After the window the
-program's state is freed and the plain reference checks a sample of what
-was served.
+(``bench/traffic/<name>.json``); the configuration names its
+architecture module (``bench/architectures/<name>.py``: sizes, weights,
+work counts) and its plain reference (``bench/references/<name>.py``);
+the metrics are read by ``bench/metrics/<name>.py`` and the correctness
+limits sit in ``bench/limits/<cell>.json``.  Set-up makes the weights
+from the seed, calibrates and compresses the LUT tables, and warms every
+shape the window uses; then the window runs for ``--seconds``
+(``--trace 1``: at most 15 s, under the profiler), under a bare
+``repro.obs.Telemetry`` whose counters the readers get.  After the
+window the program's state is freed and the plain reference checks a
+sample of what was served.
 
 The last line of standard output is one JSON object with ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown``
@@ -82,7 +85,8 @@ class Cell:
         self.bench = bench
         self.conf = model.load_config(self.spec["config"], bench)
         self.mix = traffic.load_traffic(self.spec["traffic"], bench)
-        self.m = model.dims(self.conf)
+        self.arch = model.load_architecture(self.conf, bench)
+        self.m = self.arch.dims(self.conf)
         self.vocab = self.m["V"]
 
     def metric_specs(self, per_layer: bool) -> list:
@@ -99,12 +103,12 @@ class Setup:
         import jax
 
         mix, conf = cell.mix, cell.conf
-        cfg = model.arch_config(conf)
+        cfg = cell.arch.arch_config(conf)
         with system.span("weights"):
             self.params = jax.block_until_ready(
-                model.program_params(conf, seed))
+                cell.arch.program_params(conf, seed))
         serving = conf.get("serving", {})
-        (self.lut_cfg, self.tables, self.table_bytes,
+        (self.lut_cfg, self.tables, self.site_bytes,
          self.calib_s) = system.calibrate(
             cfg, self.params,
             traffic.calibration_batch(mix, cell.vocab, seed),
@@ -136,7 +140,14 @@ class Setup:
 
 def window(cell: Cell, st: Setup, seed: int, seconds: float,
            tracer: trace.Tracer, counter: loops.CompileCounter):
-    """Run the window; returns ``(calls, loop, window_s)``."""
+    """Run the window under a bare ``repro.obs.Telemetry`` (counters in
+    memory; no event log, no monitor); returns ``(calls, loop, window_s,
+    counters)``, ``counters`` the registry's snapshot at the close."""
+    from repro import obs
+
+    tel = obs.Telemetry()
+    counters = {}
+
     def start():
         counter.start()
         tracer.start()
@@ -144,17 +155,19 @@ def window(cell: Cell, st: Setup, seed: int, seconds: float,
     def close():
         counter.stop()
         tracer.stop()
+        counters.update(tel.registry.snapshot())
 
-    if cell.mix["kind"] == "offline":
-        calls, win = loops.run_offline(st.lut_cfg, st.params, st.tables,
-                                         cell.mix, cell.vocab, seed, seconds,
-                                         on_start=start)
-        close()
-        return calls, None, win
-    loop = loops.run_open_loop(st.bat, st.arrivals, seconds,
-                                 cell.mix.get("drain_s", 60.0),
-                                 on_start=start, on_close=close)
-    return None, loop, loop.window_s
+    with tel:
+        if cell.mix["kind"] == "offline":
+            calls, win = loops.run_offline(
+                st.lut_cfg, st.params, st.tables, cell.mix, cell.vocab,
+                seed, seconds, on_start=start)
+            close()
+            return calls, None, win, counters
+        loop = loops.run_open_loop(st.bat, st.arrivals, seconds,
+                                     cell.mix.get("drain_s", 60.0),
+                                     on_start=start, on_close=close)
+    return None, loop, loop.window_s, counters
 
 
 def sample_of(cell: Cell, seed: int, calls, loop):
@@ -169,22 +182,22 @@ def readings(cell: Cell, args) -> int:
     for i in range(args.readings):
         seed = args.seed + i
         st = Setup(cell, seed, args.seconds)
-        calls, loop, _ = window(cell, st, seed, args.seconds,
-                                trace.Tracer(False),
-                                loops.CompileCounter())
+        calls, loop, _, _ = window(cell, st, seed, args.seconds,
+                                   trace.Tracer(False),
+                                   loops.CompileCounter())
         st.free()
         sample = sample_of(cell, seed, calls, loop)
-        row = {"seed": seed, **check.compare(cell.conf, seed, sample,
-                                             control=True)}
+        row = {"seed": seed, **check.compare(cell.conf, cell.bench, seed,
+                                             sample, control=True)}
         if loop is not None:
             row["finished"] = sum(s.done for s in loop.served)
             row["attempted"] = len(loop.served)
         rows.append(row)
         print(json.dumps(row), flush=True)
     worst = {k: max(r[k] for r in rows) for k in rows[0]
-             if k.startswith(("max_", "control_max_"))}
+             if k.endswith("_logit_gap")}
     least = {k: min(r[k] for r in rows) for k in rows[0]
-             if k.startswith("control_max_")}
+             if k.startswith("control_") and k.endswith("_logit_gap")}
     print(json.dumps({"cell": cell.name, "seeds": len(rows),
                       "largest": worst, "smallest_control": least}))
     return 0
@@ -222,18 +235,18 @@ def main(argv=None, *, root: Path = ROOT, bench: Path | None = None,
     tracer = trace.Tracer(bool(args.trace))
     counter = loops.CompileCounter()
     try:
-        calls, loop, win = window(cell, st, args.seed, seconds, tracer,
-                                  counter)
+        calls, loop, win, counters = window(cell, st, args.seed, seconds,
+                                            tracer, counter)
         dev = device.record(devices)
         reduced = tracer.reduced_now() if args.trace else None
     finally:
         tracer.cleanup()
-    calib_s, table_bytes = st.calib_s, st.table_bytes
+    calib_s, site_bytes = st.calib_s, st.site_bytes
     st.free()
 
     t_ref = time.perf_counter()
     sample = sample_of(cell, args.seed, calls, loop)
-    numbers = (check.compare(cell.conf, args.seed, sample)
+    numbers = (check.compare(cell.conf, bench, args.seed, sample)
                if sample is not None else {})
     ref_s = time.perf_counter() - t_ref
     correct, checks = check.judge(numbers,
@@ -246,9 +259,10 @@ def main(argv=None, *, root: Path = ROOT, bench: Path | None = None,
         failed = sum(not s.done for s in loop.served)
     correct = correct and failed == 0
     run = readers.Run(
-        kind=cell.mix["kind"], m=cell.m, peaks=pk, mix=cell.mix,
-        setup_s=setup_s, calib_s=calib_s, table_bytes=table_bytes,
-        window_s=win, calls=calls, loop=loop, trace=reduced)
+        kind=cell.mix["kind"], arch=cell.arch, m=cell.m, peaks=pk,
+        mix=cell.mix, setup_s=setup_s, calib_s=calib_s,
+        site_bytes=site_bytes, window_s=win, calls=calls, loop=loop,
+        trace=reduced, counters=counters)
     metrics = readers.read_all(bench, cell.metric_specs(bool(args.trace)),
                                run)
     result = {"correct": bool(correct), "attempted": attempted,
@@ -265,6 +279,8 @@ def main(argv=None, *, root: Path = ROOT, bench: Path | None = None,
         f"reference {ref_s:.3f} s")
     say(f"bench: compiles in set-up: {in_setup.counts}; in the window: "
         f"{counter.counts}")
+    say(f"bench: served table bytes a layer {site_bytes}; counters at "
+        f"the window's close {counters}")
     if calls is not None:
         say(f"bench: {len(calls)} calls, {attempted} sequences")
         say("bench: calls (s, call/prefill/decode/rest): " + " ".join(

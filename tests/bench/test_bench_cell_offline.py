@@ -62,6 +62,20 @@ def test_traced_run_reports_per_layer(harness_run):
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
 
 
+def test_window_counters_reach_readers(harness_run, kept_runs):
+    """The window runs under a bare ``repro.obs.Telemetry``, which keeps
+    the compiled programs: ``Run.counters`` holds every window call's
+    prefill and decode program as a hit, and no miss."""
+    rc, out = harness_run("--workload", CELL, "--seed", SEED,
+                          "--seconds", 1, "--trace", 0)
+    assert rc == 0 and json.loads(out[-1])["attempted"] > 0
+    (run,) = kept_runs
+    programs = run.counters["generate_programs_total"]
+    assert programs == {'{outcome="hit",program="decode"}': len(run.calls),
+                        '{outcome="hit",program="prefill"}': len(run.calls)}
+    assert sum(programs.values()) == 2 * len(run.calls) > 0
+
+
 def test_control_fails_the_limit(harness_run, tiny_root):
     rc, out = harness_run("--workload", CELL, "--seed", SEED,
                           "--seconds", 2, "--readings", 1)

@@ -3,13 +3,25 @@ from __future__ import annotations
 
 import json
 import re
+import sys
 from pathlib import Path
 
 import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = ROOT / "bench"
+DATA = Path(__file__).resolve().parent / "data"
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
+sys.path.insert(0, str(BENCH))
+
+from harness import model  # noqa: E402
+
+# (bench dir, configuration file): the benchmark's own, and the tests'
+# miniatures, each beside the modules it names
+CONFIG_FILES = ([(BENCH, ROOT / c["file"]) for c in MANIFEST["configs"]]
+                + [(BENCH, DATA / "configs" / "tiny.json"),
+                   (DATA / "tiny_moe",
+                    DATA / "tiny_moe" / "configs" / "tiny-moe.json")])
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -78,6 +90,26 @@ def test_every_cell_names_existing_files():
         assert (BENCH / "references" / f"{conf['reference']}.py").is_file()
         for key in c["reduced"]:
             assert NAME.match(key) and not WIDTH.search(key), key
+
+
+@pytest.mark.parametrize("bench,path", CONFIG_FILES,
+                         ids=[p.stem for _, p in CONFIG_FILES])
+def test_every_configuration_names_a_whole_architecture(bench, path):
+    conf = json.loads(path.read_text())
+    assert (bench / "architectures" / f"{conf['architecture']}.py").is_file()
+    assert (bench / "references" / f"{conf['reference']}.py").is_file()
+    arch = model.load_architecture(conf, bench)
+    assert all(callable(getattr(arch, f)) for f in model.INTERFACE)
+    assert {"L", "V"} <= set(arch.dims(conf))
+
+
+def test_a_configuration_without_an_architecture_is_refused(tmp_path):
+    conf = json.loads((BENCH / "configs" / "qwen3-0.6b.json").read_text())
+    del conf["architecture"]
+    (tmp_path / "configs").mkdir()
+    (tmp_path / "configs" / "bare.json").write_text(json.dumps(conf))
+    with pytest.raises(ValueError, match="architecture"):
+        model.load_config("bare", tmp_path)
 
 
 def test_every_metric_has_a_reader():

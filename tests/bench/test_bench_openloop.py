@@ -123,8 +123,9 @@ def test_gaps_are_tick_stamps_and_the_drain_counts():
     # requests queue behind one slot: later ones wait longer
     ttft = [s.token_s[0] - s.arrival.due_s for s in loop.served]
     assert ttft[0] < ttft[1] < ttft[2] + 0.2
-    run = readers.Run(kind="open_loop", m={}, peaks={}, mix={}, setup_s=0,
-                      calib_s=0, table_bytes=0, window_s=loop.window_s,
+    run = readers.Run(kind="open_loop", arch=None, m={}, peaks={}, mix={},
+                      setup_s=0, calib_s=0, site_bytes={},
+                      window_s=loop.window_s,
                       loop=loop)
     p90 = readers.load(BENCH, "ttft_p90_ms")(run)
     assert p90 == pytest.approx(1e3 * max(ttft))
@@ -139,8 +140,9 @@ def test_a_request_never_answered_is_missing():
     loop = loops.run_open_loop(bat, _arrivals([0.0, 0.0], max_new=400),
                                  seconds=0.05, drain_s=0.1)
     assert not loop.served[1].done and not loop.served[1].token_s
-    run = readers.Run(kind="open_loop", m={}, peaks={}, mix={}, setup_s=0,
-                      calib_s=0, table_bytes=0, window_s=loop.window_s,
+    run = readers.Run(kind="open_loop", arch=None, m={}, peaks={}, mix={},
+                      setup_s=0, calib_s=0, site_bytes={},
+                      window_s=loop.window_s,
                       loop=loop)
     assert readers.load(BENCH, "ttft_p90_ms")(run) == math.inf
 

@@ -2,8 +2,8 @@
 
 ``repro.obs.span`` writes ``repro:<span>`` annotations into a profiler
 trace; ``lower_ms``, ``compile_ms`` and ``idle_build_share.offline``
-read those spans, ``lut_ms`` reads the Pallas LUT kernels by their
-``lut_`` names.  The readers are checked on hand-made reductions, on the
+read those spans, ``lut_ms``, ``lut_roofline`` and ``lut_share`` read
+the Pallas LUT kernels by their ``lut_`` names.  The readers are checked on hand-made reductions, on the
 recorded v5e trace of a program that had neither (they find nothing
 there), and on a trace of ``generate()`` taken here on the CPU with
 telemetry off.
@@ -20,7 +20,7 @@ BENCH = Path(__file__).resolve().parents[2] / "bench"
 DATA = Path(__file__).resolve().parent / "data"
 sys.path.insert(0, str(BENCH))
 
-from harness import readers, spans, trace  # noqa: E402
+from harness import model, readers, spans, trace  # noqa: E402
 
 CALL_SPANS = ["lower.prefill", "compile.prefill", "prefill", "lower.decode",
               "compile.decode", "decode", "readback"]
@@ -30,10 +30,12 @@ def _read(name, run):
     return readers.load(BENCH, name)(run)
 
 
-def _run(red, kind="offline", n_calls=2):
+def _run(red, kind="offline", n_calls=2, arch=None, m=None, mix=None,
+         peaks=None, site_bytes=None):
     calls = list(range(n_calls)) if kind == "offline" else None
-    return readers.Run(kind=kind, m={}, peaks={}, mix={}, setup_s=0.0,
-                       calib_s=0.0, table_bytes=0.0, window_s=10.0,
+    return readers.Run(kind=kind, arch=arch, m=m or {}, peaks=peaks or {},
+                       mix=mix or {}, setup_s=0.0, calib_s=0.0,
+                       site_bytes=site_bytes or {}, window_s=10.0,
                        calls=calls, trace=red)
 
 
@@ -111,15 +113,37 @@ def test_lut_ms_is_named_kernel_time_per_call():
     assert _read("lut_ms", _run(red)) == pytest.approx(1e3 * 1.0 / 2)
 
 
+def test_lut_roofline_and_share_read_the_lut_kernels():
+    """Only the ``lut_`` kernels count, as in ``lut_ms``; the work is the
+    architecture module's count of the MLP site at the served bytes."""
+    conf = model.load_config("qwen3-0.6b", BENCH)
+    arch = model.load_architecture(conf, BENCH)
+    m = arch.dims(conf)
+    mix = {"batch": 8, "prompt_len": 2048, "new_tokens": 16}
+    peaks = {"hbm_bytes_per_s": 8.19e11}
+    red = _reduced(HOST, OPS + [_op('%closed_call.4 = bf16[8] custom-call('
+                                    '...), custom_call_target='
+                                    '"tpu_custom_call"', 0.0, 0.5)])
+    run = _run(red, arch=arch, m=m, mix=mix, peaks=peaks,
+               site_bytes={"mlp": 1000.0})
+    nbytes = 2 * arch.generate_lut(m, 8, 2048, 16, {"mlp": 1000.0})
+    assert _read("lut_roofline", run) == pytest.approx(
+        100.0 * nbytes / 8.19e11 / 1.0)
+    # LUT 1.0 s of 2.5 s busy: [0, 0.5], [1.5, 2.5], [7.5, 8], [8.5, 9]
+    assert _read("lut_share", run) == pytest.approx(100.0 * 1.0 / 2.5)
+
+
 @pytest.mark.parametrize("name", ["lower_ms", "compile_ms",
-                                  "idle_build_share.offline", "lut_ms"])
+                                  "idle_build_share.offline", "lut_ms",
+                                  "lut_roofline", "lut_share"])
 def test_readers_find_nothing_untraced_or_open_loop(name):
     assert _read(name, _run(None)) is None
     assert _read(name, _run(_reduced(HOST, OPS), kind="open_loop")) is None
 
 
 @pytest.mark.parametrize("name", ["lower_ms", "compile_ms",
-                                  "idle_build_share.offline", "lut_ms"])
+                                  "idle_build_share.offline", "lut_ms",
+                                  "lut_roofline", "lut_share"])
 def test_readers_find_nothing_in_a_program_without_spans(name):
     """The recorded trace predates the spans and the kernel names: each
     reader returns ``None`` there and does not raise."""

@@ -81,10 +81,12 @@ def test_recorded_trace():
     assert red.n_chips == 1
     assert 0.0 < red.busy_s() < red.window_s
     assert 0.0 < red.idle_share() < 1.0
-    lut = readers.load(BENCH, "lut_roofline")
-    spec = lut.__globals__
-    secs = red.op_time(spec["is_lut"])
+    # the trace predates the kernels' names: its Mosaic kernels show only
+    # as tpu_custom_call, which the LUT readers' ``lut_`` match leaves out
+    secs = red.op_time(lambda o: "tpu_custom_call" in o.meta)
     assert 0.0 < secs < red.busy_s()
+    lut = readers.load(BENCH, "lut_roofline")
+    assert red.op_time(lut.__globals__["is_lut"]) == 0.0
     names = [n for n, _ in red.named_gaps(10)]
     assert names and all(n.startswith("bench:") for n in names)
     assert any(n.startswith("bench:generate") for n in names)

@@ -1,4 +1,5 @@
-"""The benchmark's FLOP and byte counts against hand counts at
+"""The dense architecture's FLOP and byte counts
+(``bench/architectures/dense_decoder.py``) against hand counts at
 qwen3-0.6b widths."""
 from __future__ import annotations
 
@@ -7,11 +8,14 @@ from pathlib import Path
 
 import pytest
 
-sys.path.insert(0, str(Path(__file__).resolve().parents[2] / "bench"))
+BENCH = Path(__file__).resolve().parents[2] / "bench"
+sys.path.insert(0, str(BENCH))
 
-from harness import model, work  # noqa: E402
+from harness import model  # noqa: E402
 
-M = model.dims(model.load_config("qwen3-0.6b"))
+CONF = model.load_config("qwen3-0.6b", BENCH)
+work = model.load_architecture(CONF, BENCH)
+M = work.dims(CONF)
 
 
 def test_dims_of_the_config():
@@ -52,5 +56,9 @@ def test_generate_flops_prefill_heavy():
 def test_lut_bytes_by_hand():
     # 8 x 2064 tokens x 3072 elements x 28 layers, 2 B in + 2 B out, and
     # one layer's tables (1000 B) per layer call: 28 x 17 calls
-    got = work.generate_lut(M, 8, 2048, 16, 1000.0)
+    got = work.generate_lut(M, 8, 2048, 16, {"mlp": 1000.0})
     assert got == 4 * 8 * 2064 * 3072 * 28 + 1000 * 28 * 17
+    # the dense decoder serves the MLP site alone; another site's bytes
+    # are not its work
+    assert work.generate_lut(M, 8, 2048, 16,
+                             {"mlp": 1000.0, "expert": 7.0}) == got
