@@ -234,7 +234,15 @@ def capture_model(params, cfg, batches, *, w_in: int | None = None,
             if not isinstance(batch, dict):
                 batch = {"tokens": batch}
             toks = np.asarray(batch["tokens"], np.int32)
-            if cfg.family in ("dense", "moe", "vlm"):
+            if cfg.mla is not None and cfg.moe is not None:
+                # the latent stack's expert layers are the held,
+                # dropless ones (repro.nn.moe.moe_ffn_held): captured op
+                # by op, each one's tile loop would compile anew (its
+                # trip count and capture keys are its own), ~200
+                # compiles for DeepSeek-V2-Lite, so capture as one program
+                out = jax.jit(lambda p, t: decoder_forward(p, cfg, t)[0])(
+                    params, toks)
+            elif cfg.family in ("dense", "moe", "vlm"):
                 out, _, _ = decoder_forward(params, cfg, toks,
                                             patches=batch.get("patches"))
             elif cfg.family == "ssm":

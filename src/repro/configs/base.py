@@ -12,6 +12,46 @@ class MoEConfig:
     n_shared: int = 0       # shared (always-on) experts
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # The share of the router's experts this device holds (expert
+    # parallelism cut to one chip): experts first_expert ..
+    # first_expert + n_held - 1.  None holds all of them.  A latent-
+    # attention stack routes its expert layers dropless over this share
+    # (repro.nn.moe.moe_ffn_held); the other stacks drop tokens past
+    # each expert's capacity.
+    first_expert: int = 0
+    n_held: int | None = None
+    # Renormalise the top-k router weights to sum to one.
+    norm_topk_prob: bool = True
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2 section 2.1): keys and
+    values come from one low-rank latent per token, which is all the
+    decode cache holds, beside one rotary key shared by all heads."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2 applies it."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +74,14 @@ class ArchConfig:
 
     # MoE
     moe: MoEConfig | None = None
+
+    # a latent-attention decoder (DeepSeek-V2): MLA, its YaRN rotary
+    # scaling, and leading dense layers (of width d_ff_dense) before
+    # the scanned stack
+    mla: MLAConfig | None = None
+    rope_scaling: YarnScaling | None = None
+    first_k_dense: int = 0
+    d_ff_dense: int | None = None
 
     # hybrid (recurrentgemma): block pattern unit, e.g. ("rec","rec","attn")
     block_pattern: tuple[str, ...] | None = None
@@ -103,19 +151,33 @@ class ArchConfig:
             per_layer = rec * (1 - frac_attn) + attn * frac_attn
             per_layer += 3 * d * self.d_ff
         else:
-            attn = d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
+            attn = self._attn_params()
             if self.moe:
                 ff = 3 * d * self.moe.d_expert * (
-                    self.moe.n_experts + self.moe.n_shared
+                    self.moe.held + self.moe.n_shared
                 ) + d * self.moe.n_experts
             else:
                 mult = 3 if self.activation == "swiglu" else 2
                 ff = mult * d * self.d_ff
             per_layer = attn + ff
+            # leading dense layers hold a gated MLP in place of ff
+            emb += self.first_k_dense * (
+                3 * d * (self.d_ff_dense or self.d_ff) - ff)
         n = emb + int(per_layer) * self.n_layers
         if self.family == "encdec":
             n += self.n_encoder_layers * int(per_layer)
         return int(n)
+
+    def _attn_params(self) -> int:
+        d = self.d_model
+        if self.mla is None:
+            return d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
+        a, h = self.mla, self.n_heads
+        return (d * h * a.qk_head_dim
+                + d * (a.kv_lora_rank + a.qk_rope_head_dim)
+                + a.kv_lora_rank * (1 + h * (a.qk_nope_head_dim
+                                             + a.v_head_dim))
+                + h * a.v_head_dim * d)
 
     def n_active_params(self) -> int:
         """Params touched per token (MoE: only routed-active experts)."""

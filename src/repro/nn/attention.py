@@ -78,12 +78,16 @@ def mha(
     k_offset: jax.Array | int = 0,
     chunk_q: int = 512,
     exp_fn=None,
+    scale: float | None = None,
 ) -> jax.Array:
-    """General GQA attention. Returns (B, Tq, H, Dh)."""
+    """General GQA attention. Returns (B, Tq, H, Dv), ``Dv`` the value
+    head size (latent attention's values are narrower than its keys).
+    ``scale`` defaults to ``Dh**-0.5``."""
     b, tq, h, dh = q.shape
     kv = k.shape[2]
     g = h // kv
-    scale = dh ** -0.5
+    dv = v.shape[-1]
+    scale = dh ** -0.5 if scale is None else scale
     qg = q.reshape(b, tq, kv, g, dh)
     pos_k = jnp.arange(k.shape[1]) + k_offset
 
@@ -92,7 +96,7 @@ def mha(
         out = _chunk_scores(qg, k, v, pos_q, pos_k,
                             causal=causal, window=window, scale=scale,
                             exp_fn=exp_fn)
-        return out.reshape(b, tq, h, dh)
+        return out.reshape(b, tq, h, dv)
 
     pad = (-tq) % chunk_q
     if pad:  # e.g. VLM prefix: 4096 tokens + 256 patches
@@ -111,8 +115,8 @@ def mha(
                              exp_fn=exp_fn)
 
     outs = jax.lax.map(body, (qs, jnp.arange(nc)))
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, tq_p, kv, g, dh)
-    return out[:, :tq].reshape(b, tq, h, dh)
+    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, tq_p, kv, g, dv)
+    return out[:, :tq].reshape(b, tq, h, dv)
 
 
 def decode_attend(
@@ -157,6 +161,37 @@ def decode_attend(
         preferred_element_type=jnp.float32,
     ).astype(q.dtype)
     return out.reshape(b, 1, h, dh)
+
+
+def latent_decode_attend(
+    q_lat: jax.Array,      # (B, H, R): queries folded into the latent space
+    q_pe: jax.Array,       # (B, H, P): rotary part of the queries
+    c_kv: jax.Array,       # (B, Tmax, R) latent cache
+    k_pe: jax.Array,       # (B, Tmax, P) rotary key cache, shared by heads
+    c_new: jax.Array,      # (B, R): the new token's latent
+    pe_new: jax.Array,     # (B, P): the new token's rotary key
+    pos: jax.Array,        # scalar: the new token's position (0-based)
+    scale: float,
+    exp_fn=None,
+) -> jax.Array:
+    """Single-token latent attention that reads the latent cache alone.
+    Each head scores ``q_lat . c + q_pe . k_pe`` over the cached
+    positions before ``pos`` and over the new token, and returns its
+    softmax-weighted sum of latents (B, H, R); the caller applies each
+    head's value up-projection (absorbed MLA, DeepSeek-V2 section
+    2.1.2).  The new token is taken beside the cache, not from it, so a
+    step reads the cache and writes it once, after every layer."""
+    tmax = c_kv.shape[1]
+    dot = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+    s = (dot("bhr,btr->bht", q_lat.astype(c_kv.dtype), c_kv)
+         + dot("bhp,btp->bht", q_pe.astype(k_pe.dtype), k_pe))
+    s_new = (dot("bhr,br->bh", q_lat.astype(c_new.dtype), c_new)
+             + dot("bhp,bp->bh", q_pe.astype(pe_new.dtype), pe_new))
+    s = jnp.where(jnp.arange(tmax)[None, None] < pos, s, NEG_INF)
+    p = masked_softmax(jnp.concatenate([s, s_new[..., None]], -1) * scale,
+                       exp_fn)
+    return (dot("bht,btr->bhr", p[..., :tmax].astype(c_kv.dtype), c_kv)
+            + p[..., tmax:] * c_new[:, None].astype(jnp.float32))
 
 
 def ring_decode_attend(

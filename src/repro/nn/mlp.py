@@ -194,6 +194,8 @@ def site_tables(lut_tables: dict | None, site: str | None = None,
             f"per-layer LUT tables for site {site!r} need a layer index — "
             f"run the forward through run_layers (this family's loop may "
             f"not support per-layer tables)")
+    if entry.get("first_layer"):
+        layer = layer - entry["first_layer"]
     # A per-entry "backend" key (degradation ladder, serve/degrade.py)
     # overrides the top-level backend for this one site; propagate it
     # into the resolved per-layer dict so apply_lut_act sees it.

@@ -42,6 +42,7 @@ def moe_ffn_local(
     e0,                     # first resident expert id (traced or 0)
     act_name: str = "silu",
     act_fn=None,            # override (e.g. LUT-compressed expert act)
+    norm_topk_prob: bool = True,
 ):
     """Route + gather + expert GEMM + weighted scatter for local experts."""
     s, d = x.shape
@@ -49,7 +50,8 @@ def moe_ffn_local(
     logits = jnp.einsum("sd,de->se", x.astype(jnp.float32), router_w)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_ids = jax.lax.top_k(probs, top_k)          # (S, k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
     flat_ids = top_ids.reshape(-1)                        # (S*k,)
     order = jnp.argsort(flat_ids)                         # stable
@@ -138,6 +140,7 @@ def moe_block(params: dict, x: jax.Array, cfg, shared_mlp=None,
             x.reshape(-1, d), params["router"], params["w_in"],
             params["w_out"], n_experts=m.n_experts, top_k=m.top_k,
             capacity=capacity, e0=e0, act_name=act_name, act_fn=act_fn,
+            norm_topk_prob=m.norm_topk_prob,
         )
         if ep:
             y = jax.lax.psum(y, TP_AXIS)
@@ -171,6 +174,7 @@ def moe_block(params: dict, x: jax.Array, cfg, shared_mlp=None,
                 xl.reshape(-1, d), router_w, w_in, w_out,
                 n_experts=m.n_experts, top_k=m.top_k, capacity=capacity,
                 e0=e0, act_name=act_name, act_fn=act,
+                norm_topk_prob=m.norm_topk_prob,
             )
             y = jax.lax.psum(y, TP_AXIS)
             aux = jax.lax.psum(aux, TP_AXIS) / n_tp
@@ -195,9 +199,98 @@ def moe_block(params: dict, x: jax.Array, cfg, shared_mlp=None,
             x.reshape(-1, d), params["router"], params["w_in"],
             params["w_out"], n_experts=m.n_experts, top_k=m.top_k,
             capacity=capacity, e0=0, act_name=act_name, act_fn=act_fn,
+            norm_topk_prob=m.norm_topk_prob,
         )
         y = y.reshape(b, t, d)
 
     if shared_mlp is not None:
         y = y + shared_mlp(x)
     return y, aux
+
+
+def moe_ffn_held(
+    x: jax.Array,           # (S, d) tokens
+    router_w: jax.Array,    # (d, E): the router over every expert
+    w_in: jax.Array,        # (E_held, d, 2*f) fused gate|up of the held
+    w_out: jax.Array,       # (E_held, f, d)
+    *,
+    top_k: int,
+    first_expert: int = 0,
+    norm_topk_prob: bool = True,
+    act_fn=None,
+    tile: int = 512,
+):
+    """A dropless expert layer that holds experts ``first_expert ..
+    first_expert + E_held - 1`` of the router's ``E``.
+
+    Routes every token over all ``E`` experts (softmax, top ``k``,
+    renormalised only with ``norm_topk_prob``), then computes the held
+    experts' part of the result for every (token, expert) pair routed
+    here, and no other: the pairs are sorted by held expert and taken
+    ``tile`` rows at a time in a loop whose trip count is the number of
+    tiles the pairs fill.  So the work follows the pairs routed here,
+    padded by fewer than ``tile`` rows an expert, and no pair is ever
+    dropped.  Returns ``(y (S, d), counts (E_held + 1,) int32)``:
+    ``counts[e]`` pairs routed to held expert ``e``, the last entry those
+    routed to experts held elsewhere.
+    """
+    s, d = x.shape
+    e_loc = w_in.shape[0]
+    tile = min(tile, _round_up(s, 8))     # fewer rows for a small batch
+    logits = jnp.einsum("sd,de->se", x.astype(jnp.float32), router_w)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_ids = jax.lax.top_k(probs, top_k)           # (S, k)
+    if norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+
+    flat_ids = top_ids.reshape(-1)                         # (S*k,)
+    local = (flat_ids >= first_expert) & (flat_ids < first_expert + e_loc)
+    key = jnp.where(local, flat_ids - first_expert, e_loc)
+    order = jnp.argsort(key, stable=True)                  # held first
+    counts = jnp.bincount(key, length=e_loc + 1).astype(jnp.int32)
+    held = counts[:e_loc]
+    starts = jnp.cumsum(held) - held
+    tiles = (held + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles)
+    n_tiles = -(-s * top_k // tile) + e_loc               # at most
+    t = jnp.arange(n_tiles)
+    tile_e = jnp.minimum(jnp.searchsorted(tile_end, t, side="right"),
+                         e_loc - 1)
+    first_row = starts[tile_e] + (t - (tile_end - tiles)[tile_e]) * tile
+    rows_left = starts[tile_e] + held[tile_e] - first_row
+    order = jnp.concatenate([order, jnp.zeros((tile,), order.dtype)])
+    weights = top_p.reshape(-1)
+    act = act_fn if act_fn is not None else jax.nn.silu
+
+    def one_tile(i, y):
+        e = tile_e[i]
+        pairs = jax.lax.dynamic_slice(order, (first_row[i],), (tile,))
+        tok = pairs // top_k
+        h = jnp.einsum("td,df->tf", x[tok], w_in[e])
+        gate, up = jnp.split(h, 2, axis=-1)
+        out = jnp.einsum("tf,fd->td", act(gate) * up, w_out[e],
+                         preferred_element_type=jnp.float32)
+        wt = jnp.where(jnp.arange(tile) < rows_left[i], weights[pairs], 0.0)
+        return y.at[tok].add(out * wt[:, None])
+
+    y = jax.lax.fori_loop(0, tile_end[-1], one_tile,
+                          jnp.zeros((s, d), jnp.float32))
+    return y.astype(x.dtype), counts
+
+
+def held_moe_block(params: dict, x: jax.Array, cfg, lut_tables=None,
+                   layer=None):
+    """(B, T, d) -> ((B, T, d), counts): :func:`moe_ffn_held` with the
+    ``expert`` LUT site's activation for ``layer``; the shared experts
+    are the caller's."""
+    from .mlp import make_activation
+
+    b, t, d = x.shape
+    m = cfg.moe
+    act = make_activation(cfg, lut_tables, site=sites.EXPERT,
+                          fallback="silu", layer=layer)
+    y, counts = moe_ffn_held(
+        x.reshape(-1, d), params["router"], params["w_in"],
+        params["w_out"], top_k=m.top_k, first_expert=m.first_expert,
+        norm_topk_prob=m.norm_topk_prob, act_fn=act)
+    return y.reshape(b, t, d), counts

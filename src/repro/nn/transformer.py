@@ -35,9 +35,9 @@ from .layers import (
     truncated_normal_init,
 )
 from .mlp import mlp_block, project_logits, run_layers, site_act
-from .moe import moe_block
+from .moe import held_moe_block, moe_block
 from .rglru import recurrent_block, recurrent_block_step
-from .rope import apply_rope
+from .rope import apply_rope, yarn_attention_scale
 from .sharding import current_mesh, layer_scan, named_sharding, shard
 from .ssm import rwkv_channel_mix, rwkv_time_mix
 
@@ -63,6 +63,58 @@ def _attn_defs(cfg: ArchConfig, L: int, d: int) -> dict[str, ParamDef]:
     if cfg.qk_norm:
         defs["q_norm"] = ParamDef((L, cfg.d_head), (None, None), 0.0)
         defs["k_norm"] = ParamDef((L, cfg.d_head), (None, None), 0.0)
+    return defs
+
+
+def _mla_defs(cfg: ArchConfig, L: int, d: int) -> dict[str, ParamDef]:
+    """Latent attention: queries of ``nope + rope`` dims a head, one
+    ``kv_lora_rank`` latent and one rotary key a token (``wkv_a``), the
+    latent's RMSNorm, and its up-projection to each head's key and value
+    (``wkv_b``, ``[k_nope ; v]`` a head)."""
+    a, h = cfg.mla, cfg.n_heads
+    return {
+        "wq": ParamDef((L, d, h * a.qk_head_dim), (None, "fsdp", "tp")),
+        "wkv_a": ParamDef((L, d, a.kv_lora_rank + a.qk_rope_head_dim),
+                          (None, "fsdp", None)),
+        "kv_norm": ParamDef((L, a.kv_lora_rank), (None, None), 0.0),
+        "wkv_b": ParamDef(
+            (L, a.kv_lora_rank, h * (a.qk_nope_head_dim + a.v_head_dim)),
+            (None, None, "tp")),
+        "wo": ParamDef((L, h * a.v_head_dim, d), (None, "tp", "fsdp")),
+    }
+
+
+def _latent_defs(cfg: ArchConfig, defs: dict) -> dict:
+    """Latent-attention decoder: ``first_k_dense`` leading layers with a
+    dense MLP of ``d_ff_dense`` (``"lead"``), then the scanned stack
+    (``"blocks"``) of expert layers that hold ``moe.held`` experts beside
+    the shared ones."""
+    k, d = cfg.first_k_dense, cfg.d_model
+    n = cfg.n_layers - k
+
+    def norms(L):
+        return {"ln1": ParamDef((L, d), (None, None), 0.0),
+                "ln2": ParamDef((L, d), (None, None), 0.0)}
+
+    if k:
+        defs["lead"] = (_mla_defs(cfg, k, d) | norms(k)
+                        | _mlp_defs(cfg, k, d, cfg.d_ff_dense or cfg.d_ff))
+    blocks = _mla_defs(cfg, n, d) | norms(n)
+    m = cfg.moe
+    if m is None:
+        blocks |= _mlp_defs(cfg, n, d, cfg.d_ff)
+    else:
+        blocks["router"] = ParamDef((n, d, m.n_experts), (None, None, None))
+        blocks["moe_w_in"] = ParamDef(
+            (n, m.held, d, 2 * m.d_expert), (None, None, "fsdp", None))
+        blocks["moe_w_out"] = ParamDef(
+            (n, m.held, m.d_expert, d), (None, None, None, "fsdp"))
+        if m.n_shared:
+            blocks["sh_w_in"] = ParamDef(
+                (n, d, 2 * m.d_expert * m.n_shared), (None, "fsdp", "tp"))
+            blocks["sh_w_out"] = ParamDef(
+                (n, m.d_expert * m.n_shared, d), (None, "tp", "fsdp"))
+    defs["blocks"] = blocks
     return defs
 
 
@@ -161,6 +213,8 @@ def param_defs(cfg: ArchConfig) -> dict[str, Any]:
         return defs
 
     # decoder-only: dense / moe / vlm
+    if cfg.mla is not None:
+        return _latent_defs(cfg, defs)
     blocks = _attn_defs(cfg, L, d)
     blocks["ln1"] = ParamDef((L, d), (None, None), 0.0)
     blocks["ln2"] = ParamDef((L, d), (None, None), 0.0)
@@ -168,9 +222,9 @@ def param_defs(cfg: ArchConfig) -> dict[str, Any]:
         m = cfg.moe
         blocks["router"] = ParamDef((L, d, m.n_experts), (None, None, None))
         blocks["moe_w_in"] = ParamDef(
-            (L, m.n_experts, d, 2 * m.d_expert), (None, "tp", "fsdp", None))
+            (L, m.held, d, 2 * m.d_expert), (None, "tp", "fsdp", None))
         blocks["moe_w_out"] = ParamDef(
-            (L, m.n_experts, m.d_expert, d), (None, "tp", None, "fsdp"))
+            (L, m.held, m.d_expert, d), (None, "tp", None, "fsdp"))
         if m.n_shared:
             blocks["sh_w_in"] = ParamDef(
                 (L, d, 2 * m.d_expert * m.n_shared), (None, "fsdp", "tp"))
@@ -380,7 +434,13 @@ def _decoder_block(p, x, cfg, lut_tables, pos_offset=0, collect_kv=False,
 def decoder_forward(params, cfg: ArchConfig, tokens, patches=None,
                     lut_tables=None, collect_kv=False, remat=False,
                     chunk_q=512):
-    """Returns (hidden (B,T,d), aux, kv_stack | None)."""
+    """Returns (hidden (B,T,d), aux, kv_stack | None).  A latent-attention
+    config runs :func:`latent_forward` (no kv stack: its prefill builds
+    the latent cache itself)."""
+    if cfg.mla is not None:
+        x, _ = latent_forward(params, cfg, tokens, lut_tables=lut_tables,
+                              remat=remat)
+        return x, jnp.zeros((), jnp.float32), None
     x = _decoder_embed(params, cfg, tokens, patches)
 
     def body(carry, p, layer):
@@ -394,6 +454,205 @@ def decoder_forward(params, cfg: ArchConfig, tokens, patches=None,
                                  lut_tables=lut_tables, remat=remat)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, jnp.sum(auxes), kvs
+
+
+# =========================================================================
+# Latent-attention decoder (DeepSeek-V2): MLA, YaRN, leading dense layers
+# =========================================================================
+MLA_NORM_EPS = 1e-6     # the latent's RMSNorm (kv_a_layernorm)
+
+
+def _mla_project(p, x, cfg, positions, lut_tables=None, layer=None):
+    """Queries ``(q_nope (B,T,H,nope), q_pe (B,T,H,rope))`` and what the
+    cache holds of each token: ``c_kv`` (B,T,R), the normed latent, and
+    ``k_pe`` (B,T,rope), its rotated key, shared by all heads."""
+    a = cfg.mla
+    b, t, _ = x.shape
+    q = jnp.einsum("btd,dq->btq", x, p["wq"]).reshape(
+        b, t, cfg.n_heads, a.qk_head_dim)
+    kv = jnp.einsum("btd,dr->btr", x, p["wkv_a"])
+    rs = site_act(cfg, lut_tables, sites.NORM_RSQRT, layer)
+    c_kv = rms_norm(kv[..., :a.kv_lora_rank], p["kv_norm"], MLA_NORM_EPS, rs)
+    sin_fn = site_act(cfg, lut_tables, sites.ROPE, layer)
+    rope = functools.partial(apply_rope, positions=positions,
+                             theta=cfg.rope_theta, sin_fn=sin_fn,
+                             yarn=cfg.rope_scaling)
+    q_pe = rope(q[..., a.qk_nope_head_dim:])
+    k_pe = rope(kv[..., None, a.kv_lora_rank:])[:, :, 0]
+    return q[..., :a.qk_nope_head_dim], q_pe, c_kv, k_pe
+
+
+def _mla_scale(cfg) -> float:
+    return yarn_attention_scale(cfg.mla.qk_head_dim, cfg.rope_scaling)
+
+
+def _mla_chunk(b: int, h: int, t: int) -> int:
+    """Query chunk of the expanded attention: the largest power of two
+    up to 512 whose f32 score block (B, H, chunk, T) stays within 256 MiB."""
+    chunk = 512
+    while chunk > 16 and b * h * chunk * t * 4 > 2 ** 28:
+        chunk //= 2
+    return chunk
+
+
+def _mla_attn(p, x, cfg, *, lut_tables=None, layer=None):
+    """Expanded latent attention over a whole sequence (prefill): each
+    head's key ``[W_kb,i c_kv ; k_pe]`` and value ``W_vb,i c_kv``.
+    Returns ``(out, (c_kv, k_pe))``."""
+    a, h = cfg.mla, cfg.n_heads
+    b, t, _ = x.shape
+    q_nope, q_pe, c_kv, k_pe = _mla_project(p, x, cfg, jnp.arange(t),
+                                            lut_tables, layer)
+    kv = jnp.einsum("btr,rk->btk", c_kv, p["wkv_b"]).reshape(
+        b, t, h, a.qk_nope_head_dim + a.v_head_dim)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :a.qk_nope_head_dim],
+         jnp.broadcast_to(k_pe[:, :, None], (b, t, h, a.qk_rope_head_dim))],
+        axis=-1)
+    out = mha(q, k, kv[..., a.qk_nope_head_dim:], causal=True,
+              chunk_q=_mla_chunk(b, h, t), scale=_mla_scale(cfg),
+              exp_fn=site_act(cfg, lut_tables, sites.ATTN_EXP, layer))
+    out = jnp.einsum("btq,qd->btd", out.reshape(b, t, h * a.v_head_dim),
+                     p["wo"])
+    return out, (c_kv, k_pe)
+
+
+def _mla_decode(p, x, cfg, c_cache, pe_cache, pos, *, lut_tables=None,
+                layer=None):
+    """Absorbed latent attention of one new token: the key half of
+    ``W_kv_b`` is folded into the query and its value half into the
+    output, so attention reads only the layer's latent cache (B, Tmax, R)
+    and rotary key cache (B, Tmax, rope), beside the token's own entries.
+    Returns ``(out, (c_kv, k_pe))``, the token's entries (B, 1, .) in the
+    cache's dtype, which the caller writes at ``pos``."""
+    from .attention import latent_decode_attend
+
+    a, h = cfg.mla, cfg.n_heads
+    b = x.shape[0]
+    q_nope, q_pe, c_kv, k_pe = _mla_project(
+        p, x, cfg, jnp.full((1,), 0) + pos, lut_tables, layer)
+    c_kv = c_kv.astype(c_cache.dtype)
+    k_pe = k_pe.astype(pe_cache.dtype)
+    w = p["wkv_b"].reshape(a.kv_lora_rank, h,
+                           a.qk_nope_head_dim + a.v_head_dim)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0],
+                       w[..., :a.qk_nope_head_dim],
+                       preferred_element_type=jnp.float32)
+    ctx = latent_decode_attend(
+        q_lat, q_pe[:, 0], c_cache, pe_cache, c_kv[:, 0], k_pe[:, 0], pos,
+        _mla_scale(cfg),
+        exp_fn=site_act(cfg, lut_tables, sites.ATTN_EXP, layer))
+    o = jnp.einsum("bhr,rhv->bhv", ctx.astype(x.dtype),
+                   w[..., a.qk_nope_head_dim:])
+    out = jnp.einsum("bq,qd->bd", o.reshape(b, h * a.v_head_dim), p["wo"])
+    return out[:, None], (c_kv, k_pe)
+
+
+FFN_ROWS = 8192     # tokens a leading dense MLP takes at once
+
+
+def _in_row_chunks(fn, x, rows: int = FFN_ROWS):
+    """``fn`` over the tokens of ``x`` (B, T, d), ``rows`` at a time: a
+    long prefill through a wide dense MLP then holds its (rows, d_ff)
+    intermediates and not those of every token."""
+    b, t, d = x.shape
+    s = b * t
+    if s <= rows:
+        return fn(x)
+    n = -(-s // rows)
+    flat = jnp.pad(x.reshape(s, d), ((0, n * rows - s), (0, 0)))
+    out = jax.lax.map(lambda c: fn(c[None])[0], flat.reshape(n, rows, d))
+    return out.reshape(n * rows, d)[:s].reshape(b, t, d)
+
+
+def _latent_ffn(p, x, cfg, lut_tables, layer, expert_layer):
+    """The feed-forward half of a latent-attention layer: a dense MLP, or
+    (``expert_layer``) the held experts plus the shared ones.  Returns
+    ``(out, counts)``, ``counts`` None for a dense layer."""
+    if not expert_layer:
+        return _in_row_chunks(
+            lambda z: mlp_block(p, z, cfg, lut_tables, layer=layer), x), None
+    y, counts = held_moe_block(
+        {"router": p["router"], "w_in": p["moe_w_in"],
+         "w_out": p["moe_w_out"]}, x, cfg, lut_tables=lut_tables,
+        layer=layer)
+    if cfg.moe.n_shared:
+        y = y + mlp_block({"w_in": p["sh_w_in"], "w_out": p["sh_w_out"]},
+                          x, cfg, lut_tables, layer=layer)
+    return y, counts
+
+
+def latent_layers(params, cfg, x, layer_fn, carry, *, lut_tables=None,
+                  remat=False):
+    """Run the leading dense layers, then the scanned stack, through
+    ``layer_fn(p, x, carry, li, lut_layer, expert_layer) -> (x, carry,
+    y)``.  ``li`` is the global layer index (an int32 array in the
+    stack); ``lut_layer`` the LUT layer id (``None`` in a plain scan, as
+    :func:`repro.nn.mlp.run_layers` gives).  Returns ``(x, carry, ys)``,
+    ``ys`` each layer's ``y`` stacked in layer order (``None`` if the
+    layers give none)."""
+    k = cfg.first_k_dense
+    lead = []
+    for i in range(k):
+        p = jax.tree.map(lambda a, i=i: a[i], params["lead"])
+        x, carry, y = layer_fn(p, x, carry, i, i, False)
+        lead.append(y)
+
+    def body(c, inp, layer):
+        p, li = inp
+        lut_layer = None if layer is None else layer + k
+        x, carry, y = layer_fn(p, c[0], c[1], li, lut_layer,
+                               cfg.moe is not None)
+        return (x, carry), y
+
+    n = cfg.n_layers - k
+    (x, carry), ys = run_layers(
+        body, (x, carry),
+        (params["blocks"], jnp.arange(k, k + n, dtype=jnp.int32)),
+        lut_tables=lut_tables, remat=remat)
+    if lead and ys is not None:
+        ys = jax.tree.map(lambda s, *ls: jnp.concatenate([jnp.stack(ls), s]),
+                          ys, *lead)
+    return x, carry, ys
+
+
+def latent_forward(params, cfg, tokens, *, lut_tables=None, max_seq=None,
+                   remat=False):
+    """Full-sequence forward of a latent-attention decoder.  Returns
+    ``(normed hidden (B,T,d), state)``; with ``max_seq`` the state is the
+    decode state (:func:`repro.serve.kvcache.cache_specs`): the latent
+    cache of every layer filled at positions ``0..T-1``, and the expert
+    layers' assignment counts; otherwise ``None``."""
+    b, t = tokens.shape
+    x = embed_lookup(params["embed"], tokens)
+    state = None
+    if max_seq is not None:
+        from repro.serve.kvcache import init_cache
+
+        state = init_cache(cfg, b, max_seq, kv_dtype=cfg.dtype)
+
+    def layer_fn(p, x, st, li, lut_layer, expert_layer):
+        rs = site_act(cfg, lut_tables, sites.NORM_RSQRT, lut_layer)
+        h, (c_kv, k_pe) = _mla_attn(
+            p, rms_norm(x, p["ln1"], cfg.norm_eps, rs), cfg,
+            lut_tables=lut_tables, layer=lut_layer)
+        x = x + h
+        y, counts = _latent_ffn(p, rms_norm(x, p["ln2"], cfg.norm_eps, rs),
+                                cfg, lut_tables, lut_layer, expert_layer)
+        if st is not None:
+            st = dict(st)
+            for name, val in (("c_kv", c_kv), ("k_pe", k_pe)):
+                st[name] = jax.lax.dynamic_update_slice(
+                    st[name], val[None].astype(st[name].dtype),
+                    (li, 0, 0, 0))
+            if counts is not None:
+                st["expert_counts"] = st["expert_counts"] + counts
+        return x + y, st, None
+
+    x, state, _ = latent_layers(params, cfg, x, layer_fn, state,
+                                lut_tables=lut_tables, remat=remat)
+    return rms_norm(x, params["final_norm"], cfg.norm_eps), state
 
 
 def decoder_loss(params, cfg, batch, lut_tables=None, remat=False,

@@ -20,10 +20,14 @@ from repro.nn.transformer import (
     _attn_apply,
     _decode_attn,
     _decoder_embed,
+    _latent_ffn,
+    _mla_decode,
     decoder_forward,
     encoder_forward,
     encdec_forward,
     hybrid_forward,
+    latent_forward,
+    latent_layers,
     rwkv_forward,
 )
 from repro.nn.attention import mha
@@ -99,6 +103,56 @@ def decoder_decode_step(params, cfg, cache, tokens, pos,
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = project_logits(x, params["lm_head"], cfg, lut_tables)
     return logits, new_cache
+
+
+# =========================================================================
+# latent attention (DeepSeek-V2)
+# =========================================================================
+def latent_prefill(params, cfg, batch, max_seq: int | None = None,
+                   lut_tables=None):
+    """Expanded-attention prefill that writes the latent cache directly
+    at ``max_seq`` positions (no padding copy)."""
+    tokens = batch["tokens"]
+    x, cache = latent_forward(params, cfg, tokens, lut_tables=lut_tables,
+                              max_seq=max_seq or tokens.shape[1])
+    logits = project_logits(x[:, -1:], params["lm_head"], cfg, lut_tables)
+    return logits, cache
+
+
+def latent_decode_step(params, cfg, cache, tokens, pos, lut_tables=None):
+    """One token through absorbed latent attention: every layer reads
+    its rows of the latent cache, and the token's entries of all layers
+    are written at ``pos`` after the last one; the expert layers add
+    their assignments to ``cache["expert_counts"]``."""
+    from repro.nn.layers import embed_lookup
+
+    x = embed_lookup(params["embed"], tokens)
+
+    def layer_fn(p, x, counts, li, lut_layer, expert_layer):
+        rs = site_act(cfg, lut_tables, sites.NORM_RSQRT, lut_layer)
+        row = lambda a: jax.lax.dynamic_index_in_dim(a, li, 0,
+                                                     keepdims=False)
+        h, new = _mla_decode(p, rms_norm(x, p["ln1"], cfg.norm_eps, rs), cfg,
+                             row(cache["c_kv"]), row(cache["k_pe"]), pos,
+                             lut_tables=lut_tables, layer=lut_layer)
+        x = x + h
+        y, routed = _latent_ffn(p, rms_norm(x, p["ln2"], cfg.norm_eps, rs),
+                                cfg, lut_tables, lut_layer, expert_layer)
+        if routed is not None:
+            counts = counts + routed
+        return x + y, counts, new
+
+    x, counts, (c_new, pe_new) = latent_layers(
+        params, cfg, x, layer_fn, cache.get("expert_counts"),
+        lut_tables=lut_tables)
+    cache = dict(cache)
+    if counts is not None:
+        cache["expert_counts"] = counts
+    for name, val in (("c_kv", c_new), ("k_pe", pe_new)):
+        cache[name] = jax.lax.dynamic_update_slice(cache[name], val,
+                                                   (0, 0, pos, 0))
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return project_logits(x, params["lm_head"], cfg, lut_tables), cache
 
 
 # =========================================================================
@@ -208,14 +262,15 @@ DECODE_FNS = {
 
 
 def prefill(params, cfg: ArchConfig, batch, max_seq=None, lut_tables=None):
-    return PREFILL_FNS[cfg.family](params, cfg, batch, max_seq,
-                                   lut_tables=lut_tables)
+    fn = latent_prefill if cfg.mla is not None else PREFILL_FNS[cfg.family]
+    return fn(params, cfg, batch, max_seq, lut_tables=lut_tables)
 
 
 def decode_step(params, cfg: ArchConfig, cache, tokens, pos,
                 lut_tables=None):
-    return DECODE_FNS[cfg.family](params, cfg, cache, tokens, pos,
-                                  lut_tables=lut_tables)
+    fn = (latent_decode_step if cfg.mla is not None
+          else DECODE_FNS[cfg.family])
+    return fn(params, cfg, cache, tokens, pos, lut_tables=lut_tables)
 
 
 def prefill_replay(params, cfg: ArchConfig, cache, tokens, start_pos=0,

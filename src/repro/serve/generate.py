@@ -115,6 +115,10 @@ class Generation:
     decode_compile_s: float
     decode_s: float             # all new_tokens decode steps
     decode_program: object      # the compiled decode step (``as_text()``)
+    # (E_held + 1,) routed (token, expert) pairs of the call, summed over
+    # the expert layers: one per held expert, then those held elsewhere
+    # (``None`` for a model without held experts)
+    expert_counts: np.ndarray | None = None
 
     @property
     def prefill_logits(self) -> np.ndarray:
@@ -182,7 +186,9 @@ def generate(cfg, params, batch: dict, new_tokens: int, *,
     ``compile.prefill``, ``compile.decode`` (``Lowered.compile()``: a
     compile, or a persistent-cache load), both only a lookup and marked
     ``cached=True`` where the program was kept; ``prefill``, ``decode``;
-    and ``readback`` (tokens and kept logits to the host).
+    and ``readback`` (tokens and kept logits to the host, and a held
+    expert layer's assignment counts, which the decode state carries and
+    which go to the ``moe_assignments_total`` counter).
     """
     tokens = batch["tokens"]
     b, t = tokens.shape
@@ -263,8 +269,23 @@ def generate(cfg, params, batch: dict, new_tokens: int, *,
             toks = (np.concatenate([np.asarray(o) for o in outs], axis=1)
                     if outs else np.zeros((b, 0), np.int32))
             kept = [np.asarray(lg, np.float32) for lg in kept]
+            counts = (np.asarray(cache["expert_counts"])
+                      if isinstance(cache, dict) and "expert_counts" in cache
+                      else None)
+        if counts is not None:
+            _count_assignments(counts)
         return Generation(
             tokens=toks, logits=kept,
             prefill_compile_s=pre_compile_s, prefill_s=pre_s,
             decode_compile_s=dec_compile_s, decode_s=dec_s,
-            decode_program=dec_exe)
+            decode_program=dec_exe, expert_counts=counts)
+
+
+def _count_assignments(counts: np.ndarray) -> None:
+    """Add one call's routed pairs to ``moe_assignments_total``, labelled
+    by held expert (``"0"``..) or ``"elsewhere"``."""
+    names = [str(e) for e in range(len(counts) - 1)] + ["elsewhere"]
+    for name, n in zip(names, counts.tolist()):
+        obs.count("moe_assignments_total", float(n),
+                  help="routed (token, expert) pairs, summed over the "
+                       "expert layers", expert=name)

@@ -26,6 +26,16 @@ def cache_specs(cfg: ArchConfig, batch: int, max_seq: int,
     ``kv_dtype="int8"`` (decoder-only families) adds per-(pos, head) f32
     scales — the quantized-KV-cache serving mode."""
     kv, dh, L = cfg.n_kv_heads, cfg.d_head, cfg.n_layers
+    if cfg.mla is not None:
+        # the latent cache: kv_lora_rank + qk_rope_head_dim values a
+        # token a layer; and the expert layers' assignment counts
+        a = cfg.mla
+        out = {"c_kv": _leaf((L, batch, max_seq, a.kv_lora_rank), kv_dtype),
+               "k_pe": _leaf((L, batch, max_seq, a.qk_rope_head_dim),
+                             kv_dtype)}
+        if cfg.moe is not None:
+            out["expert_counts"] = _leaf((cfg.moe.held + 1,), "int32")
+        return out
     if cfg.family in ("dense", "moe", "vlm"):
         if kv_dtype == "int8":
             return {

@@ -116,6 +116,7 @@ class SitePlan:
     luts: list[LUTActivation]
     n_sites: int          # how many per-layer sites this kind covers
     per_layer: bool = False
+    first_layer: int = 0  # the layer of luts[0] (per-layer plans)
 
     @property
     def lut(self) -> LUTActivation:
@@ -136,7 +137,10 @@ class SitePlan:
         """The site entry the nn layer consumes: ``{"meta", "arrays"}``
         (shared), ``{"layers": [...]}`` (per layer, unrolled execution)
         or ``{"stacked": {...}}`` (per layer, padded ``(L, …)`` stacks
-        scanned with the in-loop layer id).
+        scanned with the in-loop layer id).  A site whose first hosting
+        layer is not 0 (the expert site after leading dense layers) adds
+        ``"first_layer"``: a layer id less that is the index into its
+        per-layer tables.
 
         ``packed=True`` returns the bit-packed slab form (Pallas backend
         only — the gather evaluators consume raw int32).  Entries are
@@ -165,6 +169,8 @@ class SitePlan:
             raise ValueError(
                 f"SitePlan.entry: unknown form {form!r} "
                 f"(expected 'stacked' or 'layers')")
+        if self.per_layer and self.first_layer:
+            out = dict(out, first_layer=self.first_layer)
         cache[key] = out
         return out
 
@@ -376,7 +382,7 @@ def _shared_specs(cfg, site_specs, calibration, w_in, w_out, x_lo, x_hi):
         metas.append(_SpecMeta(sp.key, act, quant, False, lo, hi))
     for layer in range(cfg.n_layers):
         for sp in site_specs:
-            if not sp.per_layer:
+            if not sp.per_layer or layer not in sp.layers(cfg):
                 continue
             spec, quant, act, lo, hi = tabulate(sp)
             specs.append(dataclasses.replace(spec,
@@ -422,7 +428,7 @@ def _per_site_specs(cfg, site_specs, calib: CalibrationSet, w_in, w_out,
             add(sp, None)
     for layer in range(cfg.n_layers):
         for sp in site_specs:
-            if sp.per_layer:
+            if sp.per_layer and layer in sp.layers(cfg):
                 add(sp, layer)
     return specs, metas
 
@@ -523,8 +529,11 @@ def build_serving_plans(
             if lut is not None:
                 site_plans[site].luts.append(lut)
             continue
-        site_plans[site] = SitePlan(site=site, act=meta.act, luts=[lut],
-                                    n_sites=1, per_layer=site_layered)
+        site_plans[site] = SitePlan(
+            site=site, act=meta.act, luts=[lut], n_sites=1,
+            per_layer=site_layered,
+            first_layer=(site_registry.site_spec(site).layers(cfg).start
+                         if site_layered else 0))
     return ServingPlans(arch=cfg.name, family=cfg.family, report=report,
                         sites=site_plans, backend=backend,
                         plan_exec=plan_exec, mesh=mesh,

@@ -91,6 +91,8 @@ class SiteSpec:
     families: tuple[str, ...] = ()
     enabled: Callable | None = None
     doc: str = ""
+    # absent from a config's leading dense layers (``first_k_dense``)
+    skips_dense_lead: bool = False
 
     def fn_name(self, cfg) -> str:
         return self.fn if self.fn is not None else base_activation(
@@ -107,6 +109,12 @@ class SiteSpec:
         if cfg.family not in self.families:
             return False
         return self.enabled is None or bool(self.enabled(cfg))
+
+    def layers(self, cfg) -> range:
+        """The layers that host this site (per-layer sites)."""
+        first = getattr(cfg, "first_k_dense", 0) if self.skips_dense_lead \
+            else 0
+        return range(first, cfg.n_layers)
 
     def in_scope(self, cfg) -> bool:
         """Does the config's ``lut_sites`` selector cover this site?"""
@@ -206,7 +214,7 @@ register_site(SiteSpec(
 register_site(SiteSpec(
     key=EXPERT, kind="act", fn="silu",
     families=("dense", "moe", "vlm"),
-    enabled=_has_moe,
+    enabled=_has_moe, skips_dense_lead=True,
     doc="MoE per-expert gated activation"))
 
 register_site(SiteSpec(
