@@ -33,6 +33,12 @@ unpacked in-kernel with one extra take + shift/mask, which keeps the
 VMEM-resident table bytes at the width the autotuner actually picked
 instead of 4 bytes per entry.
 
+The first two take one large row block per grid step
+(:func:`repro.kernels.ops._pick_block_rows`) and walk it in native tiles
+inside the kernel (:func:`_for_each_tile`): each grid step has a fixed
+cost of its own, so a launch runs as few steps as its shape allows,
+while each tile's arithmetic is what an 8-row block step computed.
+
 The wrappers lay every component slab out as ``(…, R, 128)`` lane rows
 (:func:`~repro.kernels.packing.lane_rows`) and the kernels read them with
 :func:`~repro.kernels.packing.lane_take`, the one table lookup Mosaic
@@ -56,6 +62,9 @@ from .runtime import resolve_interpret
 # block of an (L, k) table is not a legal TPU tiling).
 SMEM_WHOLE = pl.BlockSpec(memory_space=pltpu.SMEM)
 
+# Tiles the in-kernel loop of the per-site kernels handles per loop step.
+TILE_UNROLL = 4
+
 
 def _take(ref0, comp: str, idx, pack):
     """Component gather: direct take on raw int32 slabs, shift/mask unpack
@@ -67,27 +76,56 @@ def _take(ref0, comp: str, idx, pack):
                        per_word=p["per_word"])
 
 
+def _for_each_tile(x_ref, body) -> None:
+    """Run ``body(rows)`` over the grid step's row block one native tile
+    at a time: ``rows`` is a ``pl.ds`` slice of one ``(8, lanes)`` f32
+    tile, or ``(16, lanes)`` for a 16-bit input (its packed tile).  A
+    large block then costs one grid step but keeps each tile's
+    intermediates in vector registers; ``TILE_UNROLL`` tiles per loop
+    step let Mosaic interleave independent tiles' table reads.  A block
+    that is a single tile (or smaller, the exact-fit decode block) runs
+    ``body`` once on the whole block."""
+    block_rows = x_ref.shape[0]
+    tile = 16 if x_ref.dtype.itemsize == 2 and block_rows % 16 == 0 else 8
+    if block_rows <= tile or block_rows % tile:
+        body(slice(None))
+        return
+    n_tiles = block_rows // tile
+    unroll = next(u for u in (TILE_UNROLL, 2, 1) if n_tiles % u == 0)
+
+    def step(j, carry):
+        for u in range(unroll):
+            start = pl.multiple_of((j * unroll + u) * tile, tile)
+            body(pl.ds(start, tile))
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles // unroll, step, 0)
+
+
 def _kernel(x_ref, ust_ref, idx_ref, rsh_ref, bias_ref, lb_ref, out_ref, *,
             l, w_lb, w_hb, w_in, w_out, x_lo, x_hi, y_lo, y_hi, pack):
-    x = x_ref[...]
     levels_in = (1 << w_in) - 1
     levels_out = (1 << w_out) - 1
-    xn = jnp.clip((x.astype(jnp.float32) - x_lo) / (x_hi - x_lo), 0.0, 1.0)
-    code = jnp.round(xn * levels_in).astype(jnp.int32)
-
     m = 1 << l
-    c_hb = code >> l
-    c_lb = code & (m - 1)
-    idx = _take(idx_ref[...], "t_idx", c_hb, pack)
-    val = _take(ust_ref[...], "t_ust", idx * m + c_lb, pack)
-    val = val >> _take(rsh_ref[...], "t_rsh", c_hb, pack)
-    val = val + _take(bias_ref[...], "t_bias", c_hb, pack)
-    val = val & ((1 << max(w_hb, 1)) - 1)
-    if w_lb > 0:
-        val = (val << w_lb) | _take(lb_ref[...], "t_lb", code, pack)
 
-    y = val.astype(jnp.float32) / levels_out * (y_hi - y_lo) + y_lo
-    out_ref[...] = y.astype(out_ref.dtype)
+    def tile(rows):
+        x = x_ref[rows, :]
+        xn = jnp.clip((x.astype(jnp.float32) - x_lo) / (x_hi - x_lo),
+                      0.0, 1.0)
+        code = jnp.round(xn * levels_in).astype(jnp.int32)
+        c_hb = code >> l
+        c_lb = code & (m - 1)
+        idx = _take(idx_ref[...], "t_idx", c_hb, pack)
+        val = _take(ust_ref[...], "t_ust", idx * m + c_lb, pack)
+        val = val >> _take(rsh_ref[...], "t_rsh", c_hb, pack)
+        val = val + _take(bias_ref[...], "t_bias", c_hb, pack)
+        val = val & ((1 << max(w_hb, 1)) - 1)
+        if w_lb > 0:
+            val = (val << w_lb) | _take(lb_ref[...], "t_lb", code, pack)
+        y = val.astype(jnp.float32) / levels_out * (y_hi - y_lo) + y_lo
+        out_ref[rows, :] = y.astype(out_ref.dtype)
+
+    _for_each_tile(x_ref, tile)
 
 
 def lut_act_pallas(
@@ -177,12 +215,17 @@ def _stacked_kernel(lid_ref, x_ref, ust_ref, idx_ref, rsh_ref, bias_ref,
     the SMEM meta tables — same integer reconstruction math as
     :func:`_kernel`."""
     lid = lid_ref[0]
-    out_ref[...] = lut_eval_traced(
-        x_ref[...], ust_ref[0], idx_ref[0], rsh_ref[0], bias_ref[0],
-        lb_ref[0], mi_ref[lid, 0], mi_ref[lid, 1], mi_ref[lid, 2],
-        mf_ref[lid, 0], mf_ref[lid, 1],
-        any_lb=any_lb, w_in=w_in, w_out=w_out, x_lo=x_lo, x_hi=x_hi,
-        pack=pack, out_dtype=out_ref.dtype)
+    l, w_lb, w_hb = mi_ref[lid, 0], mi_ref[lid, 1], mi_ref[lid, 2]
+    y_lo, y_span = mf_ref[lid, 0], mf_ref[lid, 1]
+
+    def tile(rows):
+        out_ref[rows, :] = lut_eval_traced(
+            x_ref[rows, :], ust_ref[0], idx_ref[0], rsh_ref[0], bias_ref[0],
+            lb_ref[0], l, w_lb, w_hb, y_lo, y_span,
+            any_lb=any_lb, w_in=w_in, w_out=w_out, x_lo=x_lo, x_hi=x_hi,
+            pack=pack, out_dtype=out_ref.dtype)
+
+    _for_each_tile(x_ref, tile)
 
 
 def lut_act_stacked_pallas(

@@ -29,6 +29,14 @@ from .runtime import default_interpret, resolve_interpret
 
 LANES = 128
 
+# Largest row block (of 128 lanes) one grid step of the per-site LUT
+# kernels covers.  A grid step has a fixed cost (starting and awaiting
+# its DMAs, pipeline bookkeeping) of ~0.3 us on a TPU v5e, 60x what an
+# 8-row block's bytes take, so a launch should run few, large steps; the
+# value comes from a chip sweep over {256, 512, 1024, 2048} at
+# qwen3-0.6b's prefill shape (PERF.md).
+MAX_BLOCK_ROWS = 1024
+
 
 def _fault_point(point: str) -> None:
     """Serving-control-plane fault injection (repro.serve.faults): the
@@ -131,12 +139,30 @@ def _shape_2d(n: int, block_rows: int) -> tuple[int, int]:
     return rows, LANES
 
 
-def _pick_block_rows(n: int, block_rows: int = 8) -> int:
-    """Adaptive grid blocking: small decode batches (n < block_rows lanes
-    of elements) run as one exact-fit grid step instead of padding up to
-    the full 8-row block."""
+def _pick_block_rows(n: int) -> int:
+    """Row block of one grid step for ``n`` elements in ``(rows, 128)``
+    lane rows: the largest multiple of 8 that divides ``rows`` (padded to
+    a multiple of 8) and is at most :data:`MAX_BLOCK_ROWS`.  Dividing the
+    rows keeps :func:`_to_2d` a free reshape wherever the shape tiles
+    exactly.  Small decode batches (fewer than 8 rows) run as one
+    exact-fit grid step instead of padding up to 8 rows."""
     rows = -(-n // LANES)
-    return block_rows if rows >= block_rows else max(1, rows)
+    if rows < 8:
+        return max(1, rows)
+    units = -(-rows // 8)
+    return 8 * next(b for b in range(min(units, MAX_BLOCK_ROWS // 8), 0, -1)
+                    if units % b == 0)
+
+
+def _record_grid(kernel: str, rows: int, block_rows: int) -> None:
+    """Trace-time gauge ``lut_grid_steps`` (labels ``kernel``, ``rows``,
+    ``block_rows``): the grid steps one launch of that shape runs.  Set
+    while the wrapper is traced, so it costs nothing at run time."""
+    from repro import obs
+
+    obs.gauge("lut_grid_steps", rows // block_rows,
+              "grid steps a LUT kernel launch runs", kernel=kernel,
+              rows=rows, block_rows=block_rows)
 
 
 def _to_2d(x: jax.Array, block_rows: int) -> tuple[jax.Array, int]:
@@ -222,6 +248,7 @@ def lut_act(
     shape = x.shape
     block_rows = _pick_block_rows(int(np.prod(shape)))
     x2d, n = _to_2d(x, block_rows)
+    _record_grid("lut_act", x2d.shape[0], block_rows)
     out = lut_act_pallas(
         x2d,
         pa.arrays["t_ust"], pa.arrays["t_idx"], pa.arrays["t_rsh"],
@@ -269,6 +296,7 @@ def lut_act_stacked(
     shape = x.shape
     block_rows = _pick_block_rows(int(np.prod(shape)))
     x2d, n = _to_2d(x, block_rows)
+    _record_grid("lut_act_stacked", x2d.shape[0], block_rows)
     out = lut_act_stacked_pallas(
         x2d, jnp.asarray(layer, jnp.int32).reshape(1),
         a["t_ust"], a["t_idx"], a["t_rsh"], a["t_bias"], a["t_lb"],

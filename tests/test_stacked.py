@@ -289,12 +289,14 @@ def test_stacked_accounting():
 # =========================================================================
 def test_lut_act_exact_tiling_and_small_batch_blocking():
     """The ops wrapper skips the zero-fill copy on exact (rows, 128)
-    tilings and shrinks block_rows for small decode batches — both must
-    stay bit-identical to the padded path."""
+    tilings and picks its row block from the shape: the largest multiple
+    of 8 rows that divides the rows, at most ``MAX_BLOCK_ROWS``, with one
+    exact-fit step for small decode batches — all bit-identical to the
+    padded path."""
     lut = build_lut_activation("silu", RNG.normal(size=20000) * 2,
                                w_in=8, w_out=8)
     pa = lut.plan_arrays()
-    from repro.kernels.ops import _pick_block_rows, lut_act
+    from repro.kernels.ops import MAX_BLOCK_ROWS, _pick_block_rows, lut_act
 
     kw = dict(x_lo=lut.x_lo, x_hi=lut.x_hi, y_lo=lut.y_lo, y_hi=lut.y_hi,
               interpret=True)
@@ -309,6 +311,130 @@ def test_lut_act_exact_tiling_and_small_batch_blocking():
         x = RNG.uniform(-9, 9, size=n).astype(np.float32)
         got = np.asarray(lut_act(jnp.asarray(x), pa, **kw))
         np.testing.assert_array_equal(got, np.asarray(ref_fn(x)))
-    assert _pick_block_rows(2048) == 8
+    rows = lambda r: r * 128
+    assert MAX_BLOCK_ROWS % 8 == 0
+    assert _pick_block_rows(rows(393_216)) == MAX_BLOCK_ROWS  # qwen3 prefill
+    assert _pick_block_rows(rows(1024)) == 1024    # phi4 decode, 16 x 8192
+    assert _pick_block_rows(rows(5632)) == 704     # deepseek expert tile
+    assert _pick_block_rows(rows(2736)) == 912     # deepseek layer-0 decode
+    assert _pick_block_rows(2048) == 16            # one step, two tiles
+    assert _pick_block_rows(1300) == 16            # 11 rows padded to 16
     assert _pick_block_rows(256) == 2   # one exact-fit grid step
     assert _pick_block_rows(1) == 1
+    assert _pick_block_rows(rows(8 * 1031)) == 8   # 8 x a prime: 8 rows
+
+
+def test_lut_grid_steps_gauge_records_each_launch_shape():
+    """Tracing a stacked launch under an entered Telemetry sets the
+    ``lut_grid_steps`` gauge for its kernel, rows and block; with no
+    Telemetry nothing is recorded and nothing is added to the program."""
+    from repro import obs
+    from repro.kernels.ops import lut_act_stacked
+
+    entry = StackedPlanArrays.from_entries(
+        _entries(_ragged_luts(7))).entry(packed=True)
+    fn = lambda v: lut_act_stacked(v, entry, 1, interpret=True)
+    x = jax.ShapeDtypeStruct((4, 3072), jnp.bfloat16)   # 96 rows
+    with obs.Telemetry() as tel:
+        jax.eval_shape(fn, x)
+        jax.eval_shape(fn, jax.ShapeDtypeStruct((2, 128), jnp.bfloat16))
+    gauge = tel.registry.gauge("lut_grid_steps")
+    assert len(gauge.data) == 2, gauge.data
+    for rows in (96, 2):
+        assert gauge.value(kernel="lut_act_stacked", rows=rows,
+                           block_rows=rows) == 1.0, gauge.data
+    with obs.Telemetry() as tel:
+        pass
+    jax.eval_shape(fn, x)
+    assert "lut_grid_steps" not in tel.registry.snapshot()
+
+
+def _lb_luts(any_lb: bool, n_layers: int = 2):
+    """Per-layer tables whose plans all split off low bits (``w_lb`` > 0)
+    or none do."""
+    rng = np.random.default_rng(11)
+    return [build_lut_activation(
+        "silu", rng.normal(size=4000) * (2.0 + i), w_in=8, w_out=8,
+        m_candidates=(8, 16), lb_candidates=(3,) if any_lb else (0,))
+        for i in range(n_layers)]
+
+
+def _large_block_x(dtype):
+    # 96 rows: the picked block (96, below the cap) is one grid step of
+    # several tiles; 48 and 24 split it into several multi-tile steps
+    # (24 rows of a 16-bit input walk 8-row tiles)
+    x = np.random.default_rng(12).uniform(-9.0, 9.0, size=(96, 128))
+    return jnp.asarray(x, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("any_lb", [False, True], ids=["no_lb", "lb"])
+@pytest.mark.parametrize("packed", [False, True], ids=["raw", "packed"])
+def test_stacked_kernel_large_blocks_equal_the_8_row_grid(packed, any_lb,
+                                                          dtype):
+    """A grid step over a multi-tile row block (the in-kernel tile loop)
+    gives the 8-row grid's output bit for bit, and the stacked gather
+    evaluator's, on every layer."""
+    from repro.kernels.lut_act import lut_act_stacked_pallas
+    from repro.kernels.ops import _pick_block_rows
+
+    st_arr = StackedPlanArrays.from_entries(_entries(_lb_luts(any_lb)))
+    entry = st_arr.entry(packed=packed)
+    assert entry["meta"]["any_lb"] is any_lb
+    a = entry["arrays"]
+    x = _large_block_x(dtype)
+    block = _pick_block_rows(x.size)
+    assert block == 96
+
+    def kernel(block_rows):
+        return jax.jit(lambda v, lid: lut_act_stacked_pallas(
+            v, lid.reshape(1), a["t_ust"], a["t_idx"], a["t_rsh"],
+            a["t_bias"], a["t_lb"], entry["meta_i"], entry["meta_f"],
+            any_lb=entry["meta"]["any_lb"], w_in=entry["meta"]["w_in"],
+            w_out=entry["meta"]["w_out"], x_lo=entry["meta"]["x_lo"],
+            x_hi=entry["meta"]["x_hi"], pack=entry["meta"].get("pack"),
+            block_rows=block_rows, interpret=True))
+
+    raw = st_arr.entry()
+    for layer in range(2):
+        lid = jnp.asarray(layer, jnp.int32)
+        grid8 = np.asarray(kernel(8)(x, lid))
+        want = np.asarray(jax.jit(
+            lambda v, _i=layer: lut_act_jnp_stacked(v, raw, _i))(x))
+        np.testing.assert_array_equal(grid8, want)
+        for block_rows in (block, 48, 24):
+            np.testing.assert_array_equal(
+                np.asarray(kernel(block_rows)(x, lid)), grid8)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("any_lb", [False, True], ids=["no_lb", "lb"])
+@pytest.mark.parametrize("packed", [False, True], ids=["raw", "packed"])
+def test_isolated_kernel_large_blocks_equal_the_8_row_grid(packed, any_lb,
+                                                           dtype):
+    """The per-plan kernel's tile loop: multi-tile row blocks give the
+    8-row grid's output bit for bit, and the gather evaluator's."""
+    from repro.kernels import PlanArrays
+    from repro.kernels.lut_act import lut_act_pallas
+
+    lut = _lb_luts(any_lb, n_layers=1)[0]
+    assert (lut.plan.w_lb > 0) is any_lb
+    pa = PlanArrays.from_plan(lut.plan, packed=packed)
+    m = lut.meta()
+    x = _large_block_x(dtype)
+
+    def kernel(block_rows):
+        return jax.jit(lambda v: lut_act_pallas(
+            v, *(pa.arrays[c] for c in ("t_ust", "t_idx", "t_rsh",
+                                        "t_bias", "t_lb")),
+            **m, pack=pa.pack, block_rows=block_rows, interpret=True))
+
+    grid8 = np.asarray(kernel(8)(x))
+    raw = PlanArrays.from_plan(lut.plan).arrays
+    want = np.asarray(jax.jit(lambda v: lut_act_jnp(v, raw, **m))(x))
+    np.testing.assert_array_equal(grid8, want)
+    for block_rows in (96, 48, 24):
+        np.testing.assert_array_equal(np.asarray(kernel(block_rows)(x)),
+                                      grid8)

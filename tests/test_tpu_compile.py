@@ -140,6 +140,25 @@ def test_lut_act_stacked_pallas_compiles(one_chip, compiled_kernels, stack,
     assert _tpu_kernel(_compile(fn, *args), "lut_act_stacked")
 
 
+@pytest.mark.parametrize("shape", [(8, 2048, 3072), (512, 1408),
+                                   (32, 10944)],
+                         ids=["qwen3_prefill", "expert_tile", "dense_decode"])
+def test_lut_act_stacked_compiles_over_served_blocks(one_chip,
+                                                     compiled_kernels,
+                                                     stack, shape):
+    """The wrapper's row blocks at served shapes (qwen3-0.6b's prefill
+    layer, DeepSeek-V2-Lite's expert tile and layer-0 decode): one grid
+    step walks a block of up to ``MAX_BLOCK_ROWS`` rows in bf16 tiles,
+    within the kernel's VMEM."""
+    from repro.kernels.ops import lut_act_stacked
+
+    e = stack.entry(packed=True)
+    fn = lambda x, lid: lut_act_stacked(x, e, lid)
+    args = [_spec(shape, jnp.bfloat16, one_chip),
+            _spec((), jnp.int32, one_chip)]
+    assert _tpu_kernel(_compile(fn, *args), "lut_act_stacked")
+
+
 def test_lut_act_multisite_pallas_compiles(one_chip, compiled_kernels, stack):
     e = MultiSiteSlabs.from_stacks({"mlp": stack, "expert": stack}).entry()
     a = e["arrays"]
