@@ -1,5 +1,4 @@
-"""Benchmark entry point. One section per paper table/figure plus kernel
-micro-benches and the dry-run roofline table.
+"""Benchmark entry point. One section per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows (scaffold contract).
 Env: REPRO_BENCH_SCALE=small|paper (default small); paper scale reruns
@@ -11,7 +10,7 @@ import time
 
 
 def main() -> None:
-    from . import fig3, kernels_bench, roofline_bench, table2
+    from . import fig3, table2
     from .common import bench_scale
 
     print(f"# ReducedLUT benchmarks (scale={bench_scale()})")
@@ -52,12 +51,6 @@ def main() -> None:
     for r in beyond.run("jsc-2l"):
         rows.append((f"beyond_{r['model']}_{r['variant']}",
                      r["seconds"] * 1e6, f"pluts={r['pluts']}"))
-
-    print("## Kernel micro-benchmarks (interpret mode)")
-    rows += kernels_bench.run()
-
-    print("## Roofline (from dry-run artifacts, if present)")
-    rows += roofline_bench.run()
 
     print("\nname,us_per_call,derived")
     for name, us, derived in rows:

@@ -363,7 +363,7 @@ class PlanCache:
 def _record_telemetry(report: "CompressReport", cache) -> None:
     """Compression span + counters into the active telemetry, if any.
 
-    Resolved lazily through ``sys.modules`` (the ``_fault_point`` idiom):
+    The telemetry module is looked up in ``sys.modules``, never imported:
     the core engine stays importable from spawn-context pool workers with
     nothing but numpy — it must never pull in the obs package (jax) —
     and the hook is one dict lookup when telemetry is off."""

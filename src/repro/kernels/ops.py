@@ -8,7 +8,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -36,18 +35,6 @@ LANES = 128
 # value comes from a chip sweep over {256, 512, 1024, 2048} at
 # qwen3-0.6b's prefill shape (PERF.md).
 MAX_BLOCK_ROWS = 1024
-
-
-def _fault_point(point: str) -> None:
-    """Serving-control-plane fault injection (repro.serve.faults): the
-    wrapper bodies run at trace time inside jitted steps — exactly where
-    real lowering/launch failures surface — so armed injectors can stage
-    kernel faults deterministically.  Resolved lazily through
-    ``sys.modules`` so the kernels package never imports the serving
-    layer, and free when no injector is active."""
-    faults = sys.modules.get("repro.serve.faults")
-    if faults is not None and faults._ACTIVE:
-        faults.fault_point(point)
 
 
 def _pad_to(a: np.ndarray, mult: int) -> np.ndarray:
@@ -193,7 +180,6 @@ def lut_reconstruct(
     x: jax.Array, pa: PlanArrays, interpret: bool | None = None
 ) -> jax.Array:
     """Evaluate the compressed table at int addresses ``x`` (any shape)."""
-    _fault_point("pallas:lut_reconstruct")
     interpret = resolve_interpret(interpret)
     shape = x.shape
     x2d, n = _to_2d(x.reshape(-1).astype(jnp.int32), 8)
@@ -242,7 +228,6 @@ def lut_act(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused LUT-approximated activation over a float tensor of any shape."""
-    _fault_point("pallas:lut_act")
     interpret = resolve_interpret(interpret)
     assert pa.kind == "decomposed", "lut_act expects a decomposed plan"
     shape = x.shape
@@ -271,7 +256,6 @@ def lut_act_stacked(
     inside ``lax.scan``: ``layer`` may be a traced in-scan layer id; it is
     fed to the kernel as a scalar-prefetch operand so only that layer's
     table slab is staged into VMEM per grid step."""
-    _fault_point("pallas:lut_act_stacked")
     interpret = resolve_interpret(interpret)
     meta = stacked["meta"]
     a = stacked["arrays"]
@@ -329,7 +313,6 @@ def lut_act_multi(
     against the shared super-slab instead of per-site programs with
     per-site table uploads.
     """
-    _fault_point("pallas:lut_act_multi")
     interpret = resolve_interpret(interpret)
     meta = entry["meta"]
     site_order = meta["sites"]

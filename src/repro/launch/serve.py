@@ -115,30 +115,7 @@ def _build_parser() -> argparse.ArgumentParser:
                          "compression (implies --lut-act)")
     ap.add_argument("--save-plan", default=None, metavar="PATH",
                     help="freeze the built serving plans into a tuned-plan "
-                         "artifact at PATH (reload-ready: a hot reload of "
-                         "a frozen plan is parity-gate-trivial)")
-    ap.add_argument("--reload-plan", default=None, metavar="PATH",
-                    help="serve through the continuous batcher and "
-                         "hot-reload the tuned-plan artifact at PATH "
-                         "mid-decode behind the parity gate (single "
-                         "device; see serve/reload.py)")
-    ap.add_argument("--watch", action="store_true",
-                    help="with --reload-plan: poll PATH for mtime changes "
-                         "every tick instead of a one-shot scheduled "
-                         "reload")
-    ap.add_argument("--degrade", action="store_true",
-                    help="attach the per-site backend degradation ladder "
-                         "(pallas_fused -> pallas -> gather -> float) as "
-                         "the batcher's fault supervisor")
-    ap.add_argument("--slo-ms", type=float, default=None,
-                    help="per-request latency objective; violations are "
-                         "counted in the serving metrics")
-    ap.add_argument("--reload-max-drop", type=float, default=0.01,
-                    help="parity-gate budget: max top-1 agreement drop vs "
-                         "the active plan (paper contract: 0.01)")
-    ap.add_argument("--reload-gate-tokens", type=int, default=4,
-                    help="greedy tokens per shadow row that must match "
-                         "the active plan at the gate")
+                         "artifact at PATH")
     ap.add_argument("--mesh", default=None, metavar="DP,TP",
                     help="serve on a (data, model) host mesh, e.g. 2,2 — "
                          "data-parallel batch x bit-exact tensor-parallel "
@@ -218,7 +195,7 @@ def _main(ap, args, tel) -> None:
              for k, v in model_batch(cfg, rng, b, t).items()}
 
     lut_tables = None
-    plan_source = None   # ServingPlans/TunedPlan for the ladder
+    plan_source = None   # ServingPlans built in-process, for --save-plan
     if args.tuned_plan:
         from repro.tune import load_tuned_plan
 
@@ -231,7 +208,6 @@ def _main(ap, args, tel) -> None:
             tp = load_tuned_plan(args.tuned_plan)
         except ValueError as e:   # includes ArtifactError (corrupt file)
             ap.error(f"--tuned-plan: {e}")
-        plan_source = tp
         cfg = tp.patched_config(cfg)   # binds artifact to this arch/depth
         lut_tables = tp.tables_for_model(backend=args.lut_backend,
                                          plan_exec=args.plan_exec,
@@ -297,23 +273,15 @@ def _main(ap, args, tel) -> None:
                      table_bytes=tables_nbytes(lut_tables))
 
     if args.save_plan:
-        if plan_source is None or args.tuned_plan:
+        if plan_source is None:
             ap.error("--save-plan needs --lut-act plans built in-process "
                      "(a --tuned-plan artifact already is one)")
         from repro.tune import save_tuned_plan, tuned_plan_from_serving
 
         frozen = save_tuned_plan(args.save_plan,
                                  tuned_plan_from_serving(cfg, plan_source))
-        log.info("plan_saved", f"saved tuned plan -> {frozen} "
-                 f"(reload-ready)", path=frozen)
-
-    if args.reload_plan:
-        if mesh is not None:
-            ap.error("--reload-plan is single-device — the control plane "
-                     "swaps jitted closures, not placed tables")
-        _serve_with_reload(args, cfg, params, lut_tables, plan_source,
-                           batch, lut_kernel, tel)
-        return
+        log.info("plan_saved", f"saved tuned plan -> {frozen}",
+                 path=frozen)
 
     serve = None
     if mesh is not None:
@@ -352,116 +320,6 @@ def _main(ap, args, tel) -> None:
              tok_s=round(n / gen.decode_s, 2) if gen.decode_s else 0.0)
     req0 = [int(v) for v in gen.tokens[0]]
     log.info("request_tokens", f"request 0: {req0}", rid=0, tokens=req0)
-
-
-def _serve_with_reload(args, cfg, params, lut_tables, plan_source, batch,
-                       lut_kernel, tel=None) -> None:
-    """Serve through the continuous batcher with the resilience control
-    plane attached: a :class:`~repro.serve.reload.PlanReloader` hot-loads
-    ``--reload-plan`` mid-decode behind the parity gate (one-shot at the
-    decode midpoint, or mtime-polled with ``--watch``), optionally
-    chained with the :class:`~repro.serve.degrade.DegradationLadder`.
-    Exits non-zero when a scheduled reload never cut over or any request
-    was dropped."""
-    import sys
-
-    from repro.serve import (
-        CompositeSupervisor,
-        ContinuousBatcher,
-        DegradationLadder,
-        PlanReloader,
-        Request,
-    )
-
-    b, t = args.batch, args.prompt_len
-    max_seq = t + args.new_tokens
-    batcher = ContinuousBatcher(
-        cfg, params, b, max_seq, eos_token=-1,
-        kv_dtype="int8" if args.kv_int8 else "bfloat16",
-        lut_tables=lut_tables, prefill="replay")
-    ladder = None
-    if args.degrade:
-        if plan_source is None:
-            log.warn("ladder_skipped",
-                     "--degrade: no LUT plans in this serving config — "
-                     "ladder not attached (float path only)")
-        else:
-            if lut_kernel == "fused":
-                top = "pallas_fused"
-            elif args.lut_backend == "pallas":
-                top = "pallas"
-            else:
-                top = "gather"
-            ladder = DegradationLadder(plan_source,
-                                       plan_exec=args.plan_exec,
-                                       top_rung=top)
-    reloader = PlanReloader(batcher, cfg, params,
-                            backend=args.lut_backend,
-                            plan_exec=args.plan_exec, kernel=lut_kernel,
-                            max_top1_drop=args.reload_max_drop,
-                            gate_tokens=args.reload_gate_tokens,
-                            ladder=ladder)
-    batcher.supervisor = CompositeSupervisor(reloader, ladder)
-    if args.watch:
-        reloader.watch(args.reload_plan)
-        log.info("reload_watch",
-                 f"watching {args.reload_plan} for plan updates",
-                 path=args.reload_plan)
-    else:
-        at_tick = max(1, args.new_tokens // 2)
-        reloader.schedule(args.reload_plan, at_tick)
-        log.info("reload_scheduled",
-                 f"hot reload of {args.reload_plan} scheduled at decode "
-                 f"tick {at_tick}", path=args.reload_plan, at_tick=at_tick)
-
-    prompts = np.asarray(batch["tokens"])
-    for i in range(b):
-        batcher.submit(Request(rid=i, prompt=[int(x) for x in prompts[i]],
-                               max_new=args.new_tokens,
-                               slo_ms=args.slo_ms))
-    t0 = time.time()
-    finished = batcher.run()
-    dt = time.time() - t0
-
-    for rec in reloader.records:
-        log.info("reload_record", rec.summary())
-    if ladder is not None:
-        log.info("ladder_status",
-                 "ladder: " + " ".join(f"{s}={r}" for s, r
-                                       in ladder.status().items()),
-                 **ladder.status())
-    m = batcher.metrics()
-    log.info("serve_summary",
-             f"served {m['finished']}/{m['submitted']} requests in "
-             f"{dt:.2f}s ({m['ticks']} ticks, utilization "
-             f"{m['utilization']:.2f}, {m['table_swaps']} table swaps)",
-             finished=m["finished"], submitted=m["submitted"],
-             seconds=round(dt, 3), ticks=m["ticks"],
-             utilization=round(m["utilization"], 4),
-             table_swaps=m["table_swaps"])
-    log.info("serve_latency",
-             f"latency p50 {m['latency_p50_s']:.3f}s p95 "
-             f"{m['latency_p95_s']:.3f}s; "
-             f"SLO violations {m['slo_violations']}/{m['slo_tracked']}",
-             latency_p50_s=m["latency_p50_s"],
-             latency_p95_s=m["latency_p95_s"],
-             slo_violations=m["slo_violations"],
-             slo_tracked=m["slo_tracked"])
-    log.info("reload_counters", f"reload counters: {reloader.counters}",
-             **reloader.counters)
-    req0 = next(r for r in finished if r.rid == 0)
-    log.info("request_tokens", f"request 0: {req0.out}",
-             rid=0, tokens=req0.out)
-    if m["dropped"]:
-        log.error("requests_dropped",
-                  f"ERROR: {m['dropped']} request(s) dropped across the "
-                  f"reload", dropped=m["dropped"])
-        sys.exit(2)
-    if not args.watch and not reloader.counters["reloads_ok"]:
-        log.error("reload_never_cutover",
-                  "ERROR: scheduled hot reload never cut over — see the "
-                  "rejection records above")
-        sys.exit(1)
 
 
 if __name__ == "__main__":

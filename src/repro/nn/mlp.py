@@ -196,22 +196,12 @@ def site_tables(lut_tables: dict | None, site: str | None = None,
             f"not support per-layer tables)")
     if entry.get("first_layer"):
         layer = layer - entry["first_layer"]
-    # A per-entry "backend" key (degradation ladder, serve/degrade.py)
-    # overrides the top-level backend for this one site; propagate it
-    # into the resolved per-layer dict so apply_lut_act sees it.
-    bk = entry.get("backend")
     if "multi" in entry:
-        out = {"multi_entry": lut_tables["multi"], "site": entry["multi"],
-               "layer": layer}
-    elif "stacked" in entry:
-        out = {"stacked": entry["stacked"], "layer": layer}
-    else:
-        out = entry["layers"][layer]
-        if bk is not None:
-            out = dict(out)
-    if bk is not None:
-        out["backend"] = bk
-    return out
+        return {"multi_entry": lut_tables["multi"], "site": entry["multi"],
+                "layer": layer}
+    if "stacked" in entry:
+        return {"stacked": entry["stacked"], "layer": layer}
+    return entry["layers"][layer]
 
 
 def entry_operands(tab: dict):
@@ -229,8 +219,6 @@ def entry_operands(tab: dict):
         raise ValueError(
             "entry_operands: multi-site fused tables are the single-device "
             "fast path — build mesh tables with kernel='isolated'")
-    bk = tab.get("backend")
-    extra = {"backend": bk} if bk is not None else {}
     if "stacked" in tab:
         st = tab["stacked"]
         meta = st["meta"]
@@ -242,14 +230,14 @@ def entry_operands(tab: dict):
             return {"stacked": {"meta": meta, "arrays": ops["arrays"],
                                 "meta_i": ops["meta_i"],
                                 "meta_f": ops["meta_f"]},
-                    "layer": ops["layer"], **extra}
+                    "layer": ops["layer"]}
 
         return ops, rebuild
     meta = tab["meta"]
     ops = {"arrays": tab["arrays"]}
 
     def rebuild(ops):
-        return {"meta": meta, "arrays": ops["arrays"], **extra}
+        return {"meta": meta, "arrays": ops["arrays"]}
 
     return ops, rebuild
 
@@ -264,13 +252,7 @@ def apply_lut_act(x, tab: dict, backend: str = "gather"):
     math and bit-match each other (tests/test_serve_plans.py), in the
     per-plan form and the layer-indexed stacked form alike
     (tests/test_stacked.py).
-
-    A ``"backend"`` key on the resolved entry (the degradation ladder's
-    per-site override) wins over the caller's ``backend`` — demoted
-    sites ride the gather form while healthy ones keep Pallas, with
-    identical outputs by the bit-identity contract.
     """
-    backend = tab.get("backend", backend)
     if "multi_entry" in tab:
         if backend != "pallas":
             raise ValueError(
@@ -338,11 +320,7 @@ def fused_matmul_tab(cfg, lut_tables: dict | None, site: str,
     spec = sites.site_spec(site)
     if not spec.active(cfg):
         return None
-    tab = site_tables(lut_tables, site, layer if spec.per_layer else None)
-    if tab is not None and tab.get("backend", "pallas") != "pallas":
-        # ladder-demoted site: keep the unfused gather composition
-        return None
-    return tab
+    return site_tables(lut_tables, site, layer if spec.per_layer else None)
 
 
 def make_activation(cfg, lut_tables: dict | None, site: str | None = None,

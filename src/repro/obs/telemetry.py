@@ -1,7 +1,7 @@
 """Telemetry context: one object binding registry + event log + monitor.
 
 The activation idiom is :mod:`repro.calib.capture`'s module-level stack:
-instrumented code (batcher, reloader, ladder, engine, kernel wrappers)
+instrumented code (batcher, engine, kernel wrappers)
 asks :func:`current` for the innermost active :class:`Telemetry` and
 does nothing when there is none — off-by-default telemetry costs one
 ``None`` check on host code paths and adds **zero traced ops** to jitted

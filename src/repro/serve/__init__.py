@@ -1,10 +1,7 @@
-"""Serving runtime: prefill, decode, KV-cache management, batching,
-compressed-activation serving plans, and the resilience control plane
-(gated hot reload, degradation ladder, fault injection)."""
+"""Serving runtime: prefill, decode, KV-cache management, batching and
+compressed-activation serving plans."""
 from .batching import ContinuousBatcher, Request
 from .decode import decode_step, prefill, prefill_replay
-from .degrade import RUNGS, CompositeSupervisor, DegradationLadder
-from .faults import FaultInjector, corrupt_file, corrupt_rung, corrupt_tables
 from .generate import Generation, generate
 from .kvcache import cache_shardings, cache_specs, init_cache
 from .plans import (
@@ -22,7 +19,6 @@ from .sharded import (
     serve_cache_shardings,
     serve_param_shardings,
 )
-from .reload import PlanReloader, ReloadRecord
 from .stacked import StackedPlanArrays, tables_nbytes
 
 __all__ = ["prefill", "decode_step", "prefill_replay", "generate",
@@ -32,6 +28,4 @@ __all__ = ["prefill", "decode_step", "prefill_replay", "generate",
            "activation_sites", "build_serving_plans", "tables_nbytes",
            "verify_backend_equivalence", "ShardedServe", "PlacementPolicy",
            "place_tables", "plan_placement_report", "serve_param_shardings",
-           "serve_cache_shardings", "RUNGS", "CompositeSupervisor",
-           "DegradationLadder", "FaultInjector", "corrupt_file",
-           "corrupt_rung", "corrupt_tables", "PlanReloader", "ReloadRecord"]
+           "serve_cache_shardings"]

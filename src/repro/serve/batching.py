@@ -11,16 +11,8 @@ tracking, admission, eviction-on-EOS/length, utilization accounting) —
 the part that is identical at cluster scale; the step function underneath
 is the same one the 512-chip dry-run lowers.
 
-Control-plane hooks (serve/reload.py, serve/degrade.py):
+Beyond scheduling:
 
-* :meth:`ContinuousBatcher.swap_tables` atomically replaces the served
-  plan between ticks — in-flight slots keep their cache rows and
-  positions, only the step closures are rebuilt (gated hot reload,
-  ladder demotion/promotion; all LUT rungs are bit-identical, so a swap
-  above the float rung never changes served tokens);
-* a ``supervisor`` object (``on_tick(batcher)`` / ``on_fault(batcher,
-  exc) -> bool``) observes every tick and may handle step faults by
-  swapping tables and requesting a retry;
 * :meth:`run` detects no-progress ticks (a request that can never be
   admitted or advanced) and raises naming the stuck request instead of
   spinning to ``max_ticks``;
@@ -85,7 +77,7 @@ class ContinuousBatcher:
     def __init__(self, cfg: ArchConfig, params, batch_size: int,
                  max_seq: int, eos_token: int = 0,
                  kv_dtype: str = "bfloat16", lut_tables: dict | None = None,
-                 prefill: str = "step", mesh=None, supervisor=None):
+                 prefill: str = "step", mesh=None):
         if prefill not in ("step", "replay"):
             raise ValueError(
                 f"prefill must be 'step' or 'replay', got {prefill!r}")
@@ -96,11 +88,10 @@ class ContinuousBatcher:
         self.prefill = prefill
         self.mesh = mesh
         self.kv_dtype = kv_dtype
-        self.supervisor = supervisor
         self.lut_tables = lut_tables
         self.params = params
         self.cache = init_cache(cfg, batch_size, max_seq, kv_dtype)
-        self._build_step_fns(first=True)
+        self._build_step_fns()
         self.slots = [_Slot() for _ in range(batch_size)]
         self.queue: deque[Request] = deque()
         self.finished: list[Request] = []
@@ -108,9 +99,8 @@ class ContinuousBatcher:
         self.active_slot_steps = 0
         self.replayed_tokens = 0
         self.submitted = 0
-        self.table_swaps = 0
 
-    def _build_step_fns(self, first: bool = False) -> None:
+    def _build_step_fns(self) -> None:
         cfg = self.cfg
         if self.mesh is not None:
             # Sharded serving: data-parallel batch pool x (bit-exact)
@@ -123,9 +113,8 @@ class ContinuousBatcher:
             self._serve = ShardedServe(cfg, self.mesh, self.lut_tables,
                                        kv_dtype=self.kv_dtype)
             self.lut_tables = self._serve.tables
-            if first:
-                self.params = self._serve.place_params(self.params)
-                self.cache = self._serve.place_cache(self.cache)
+            self.params = self._serve.place_params(self.params)
+            self.cache = self._serve.place_cache(self.cache)
             self._replay = lambda p, c, toks: self._serve.replay(
                 p, c, toks, 0)
             self._step = self._serve.decode
@@ -171,43 +160,6 @@ class ContinuousBatcher:
                 and self.steps % mon.sample_every != 0):
             return self._step_plain
         return self._step
-
-    def swap_tables(self, lut_tables: dict | None,
-                    cfg: ArchConfig | None = None) -> None:
-        """Atomically swap the served plan (and optionally the patched
-        config) between scheduler ticks: in-flight slots keep their cache
-        rows and positions; only the jitted step closures are rebuilt.
-        The hot-reload cutover and every ladder demotion/promotion go
-        through here — above the float rung all plans are bit-identical,
-        so a swap never changes served tokens."""
-        if cfg is not None:
-            self.cfg = cfg
-        self.lut_tables = lut_tables
-        self._build_step_fns()
-        self.table_swaps += 1
-        obs.count("batcher_table_swaps_total")
-        obs.event("table_swap", tick=self.steps, swaps=self.table_swaps,
-                  backend=(lut_tables or {}).get("backend", "float"))
-
-    def _guarded(self, thunk):
-        """Run one jitted serving call under the supervisor's fault
-        policy: on an exception the supervisor may swap tables (ladder
-        demotion, reload rollback) and have the call retried with the
-        rebuilt closures.  Bounded so an unhandled repeated fault still
-        surfaces — the ladder demotes at most to the float rung in one
-        pass, so real recoveries converge in one or two retries."""
-        for _ in range(6):
-            try:
-                return thunk()
-            except Exception as e:
-                obs.count("serve_faults_total")
-                obs.event("serve_fault", tick=self.steps,
-                          error=f"{type(e).__name__}: {e}")
-                if (self.supervisor is None
-                        or not self.supervisor.on_fault(self, e)):
-                    raise
-        raise RuntimeError(
-            "serving fault persisted after 6 supervised retries")
 
     def submit(self, req: Request) -> None:
         if not req.prompt:
@@ -287,8 +239,8 @@ class ContinuousBatcher:
         snap = {name: self.cache[name][:, others, :n]
                 for name in self.cache if name in
                 ("k", "v", "k_scale", "v_scale")}
-        logits, self.cache = self._guarded(lambda: self._replay(
-            self.params, self.cache, jnp.asarray(tokens)))
+        logits, self.cache = self._replay(
+            self.params, self.cache, jnp.asarray(tokens))
         if others:
             oth = jnp.asarray(others)
             for name, before in snap.items():
@@ -357,12 +309,9 @@ class ContinuousBatcher:
             snap = {name: self.cache[name][:, :, pos]
                     for name in self.cache if name in
                     ("k", "v", "k_scale", "v_scale")}
-            # pick inside the thunk: a supervisor fault handler may swap
-            # tables and rebuild the step closures, and the retry must
-            # run the rebuilt program, not the one bound pre-fault
-            logits, self.cache = self._guarded(lambda: self._pick_step()(
+            logits, self.cache = self._pick_step()(
                 self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(pos)))
+                jnp.asarray(pos))
             if others:
                 oth = jnp.asarray(others)
                 for name, before in snap.items():
@@ -408,18 +357,13 @@ class ContinuousBatcher:
             stall_ticks: int = 4) -> list[Request]:
         """Drive the scheduler until the queue drains (or ``max_ticks``).
 
-        The supervisor's ``on_tick`` runs *between* ticks — reload
-        cutovers and ladder promotions land here, never mid-step.  A
-        tick that neither finishes a request, advances a slot, nor
+        A tick that neither finishes a request, advances a slot, nor
         replays prompt tokens makes no progress; ``stall_ticks``
         consecutive ones mean some request can never be admitted or
         advanced (e.g. a zero-slot pool) — raise naming it instead of
         spinning to ``max_ticks``."""
         stalled = 0
         while (self.queue or self.n_active) and self.steps < max_ticks:
-            if (self.supervisor is not None
-                    and hasattr(self.supervisor, "on_tick")):
-                self.supervisor.on_tick(self)
             before = (len(self.finished), self.active_slot_steps,
                       self.replayed_tokens)
             self.step()
@@ -446,9 +390,9 @@ class ContinuousBatcher:
         return self.active_slot_steps / (self.steps * self.b)
 
     def metrics(self) -> dict:
-        """Control-plane observability snapshot: request accounting
-        (anything submitted but neither finished, queued, nor in-flight
-        counts as dropped — asserted zero in the robustness suite),
+        """Observability snapshot: request accounting (anything
+        submitted but neither finished, queued, nor in-flight counts as
+        dropped),
         latency/TTFT percentiles over finished requests, and SLO
         violations for requests that carried a target."""
         lats = sorted(r.latency_s for r in self.finished
@@ -477,7 +421,6 @@ class ContinuousBatcher:
             "ticks": self.steps,
             "utilization": self.utilization,
             "replayed_tokens": self.replayed_tokens,
-            "table_swaps": self.table_swaps,
             "latency_p50_s": pct(lats, 0.50),
             "latency_p95_s": pct(lats, 0.95),
             "latency_max_s": float(lats[-1]) if lats else 0.0,

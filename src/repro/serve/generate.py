@@ -25,7 +25,6 @@ from repro.calib.capture import capture_active
 from repro.obs.drift import monitor_active
 
 from .decode import decode_step, prefill, prefill_replay
-from .faults import injection_active
 from .kvcache import init_cache
 
 MAX_PROGRAMS = 8
@@ -82,10 +81,10 @@ def clear_programs() -> None:
 
 
 def _hook_entered() -> bool:
-    """True while a trace-time hook is entered (an activation capture, a
-    don't-care monitor, a fault injector): a program traced then calls
-    into the hook, so it is built for that call alone and not kept."""
-    return capture_active() or monitor_active() or injection_active()
+    """True while a trace-time hook is entered (an activation capture or
+    a don't-care monitor): a program traced then calls into the hook, so
+    it is built for that call alone and not kept."""
+    return capture_active() or monitor_active()
 
 
 def _signature(*args) -> tuple:

@@ -44,7 +44,7 @@ selected plans into serve/lut_act end-to-end"):
    Both runtime backends — ``"gather"`` (GSPMD-shardable ``jnp.take``)
    and ``"pallas"`` (fused quantize/reconstruct/dequantize kernel) —
    bit-match under either calibration mode and either execution form
-   (:func:`verify_backend_equivalence`, asserted in tests and the bench).
+   (:func:`verify_backend_equivalence`, asserted in tests).
 """
 from __future__ import annotations
 
@@ -307,13 +307,6 @@ class ServingPlans:
 
     def patched_config(self, cfg: ArchConfig) -> ArchConfig:
         return dataclasses.replace(cfg, lut_activation=True)
-
-    def fused_available(self, plan_exec: str | None = None) -> bool:
-        """True when these plans can serve the fused multi-site kernel
-        (Pallas + stacked execution + per-layer sites, single device) —
-        the top rung of the serving degradation ladder."""
-        exec_ = plan_exec or self.plan_exec
-        return exec_ == "stacked" and self.per_layer and not self.mesh
 
     @property
     def per_layer(self) -> bool:

@@ -369,8 +369,9 @@ def multi_site_stacked_entry(entry: dict, site: str) -> dict:
 
 def tables_nbytes(lut_tables: dict) -> int:
     """Total device bytes of every array in a ``lut_tables`` dict — the
-    upload cost of a serving-table form (used by serve_bench to price the
-    stacked padding overhead against the unrolled layout)."""
+    upload cost of a serving-table form (the table bytes
+    ``launch/serve`` prints and :meth:`~repro.serve.plans.ServingPlans.table_bytes`
+    returns)."""
     leaves = jax.tree.leaves(lut_tables)
     return sum(int(a.size) * a.dtype.itemsize
                for a in leaves if hasattr(a, "dtype"))

@@ -119,13 +119,6 @@ class TunedPlan:
                 f"site, config expects {cfg.n_layers}")
         return dataclasses.replace(cfg, lut_activation=True)
 
-    def fused_available(self, plan_exec: str | None = None) -> bool:
-        """True when these plans can serve the fused multi-site kernel
-        (Pallas + stacked execution + at least one per-layer site) — the
-        top rung of the serving degradation ladder."""
-        exec_ = plan_exec or self.plan_exec
-        return exec_ == "stacked" and any(self.per_layer.values())
-
     @property
     def total_cost(self) -> int:
         return int(self.meta.get("cost", 0))
@@ -186,8 +179,8 @@ def tuned_plan_from_serving(cfg: ArchConfig, plans,
     """Freeze built :class:`~repro.serve.plans.ServingPlans` into an
     artifact without an autotune sweep — the ``launch/serve --save-plan``
     path.  The stored entries are the exact device arrays the plans
-    serve, so a hot reload of a frozen plan is parity-gate-trivial
-    (token-identical to the serving that produced it)."""
+    serve, so serving the artifact is token-identical to the serving
+    that produced it."""
     from repro.kernels import PlanArrays
 
     sites: dict[str, list[dict]] = {}

@@ -19,11 +19,11 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.calib import (calibration_from_capture, capture_model,
-                         model_batch, synthetic_batches)
+from repro.calib import (ActivationCapture, calibration_from_capture,
+                         capture_model, model_batch, synthetic_batches)
 from repro.configs import get_config, smoke_config
 from repro.nn import init_params
-from repro.serve import FaultInjector, build_serving_plans
+from repro.serve import build_serving_plans
 
 gen = importlib.import_module("repro.serve.generate")
 
@@ -47,12 +47,18 @@ def traces():
 
 
 @pytest.fixture(scope="module")
-def served():
+def calibrated():
     cfg = smoke_config(get_config("qwen3-0.6b"))
     params = init_params(cfg, jax.random.PRNGKey(0))
     cap = capture_model(params, cfg, synthetic_batches(
         cfg, 1, batch_size=2, seq_len=8, seed=1), w_in=8)
-    plans = build_serving_plans(cfg, calibration_from_capture(cap), w_out=8)
+    return cfg, params, calibration_from_capture(cap)
+
+
+@pytest.fixture(scope="module")
+def served(calibrated):
+    cfg, params, calib = calibrated
+    plans = build_serving_plans(cfg, calib, w_out=8)
     batch = model_batch(cfg, np.random.default_rng(0), 2, 6)
     return plans.patched_config(cfg), params, plans, batch
 
@@ -158,14 +164,23 @@ def test_cleared_programs_are_traced_again(served, traces):
     assert traces() > n
 
 
-def test_nothing_is_kept_under_a_trace_time_hook(served):
-    """A fault injector, like a capture or a drift monitor, is called
-    from inside the traced program: such a program is not kept."""
-    cfg, params, _, batch = served
+# each case: a trace-time hook, entered around the calls
+HOOKS = {
+    "activation_capture": lambda calib: ActivationCapture(w_in=calib.w_in),
+    "dontcare_monitor": lambda calib: obs.DontCareMonitor(calib),
+}
+
+
+@pytest.mark.parametrize("hook", list(HOOKS))
+def test_nothing_is_kept_under_a_trace_time_hook(served, calibrated, hook):
+    """A capture or a drift monitor is called from inside the traced
+    program: such a program is not kept."""
+    cfg, params, plans, batch = served
+    tables = plans.tables_for_model(backend="gather")
     gen.clear_programs()
-    with FaultInjector(), obs.Telemetry() as tel:
+    with HOOKS[hook](calibrated[2]), obs.Telemetry() as tel:
         for _ in range(2):
-            gen.generate(cfg, params, batch, NEW)
+            gen.generate(cfg, params, batch, NEW, lut_tables=tables)
     assert len(gen._PROGRAMS) == 0
     out = _outcomes(tel)
     assert out[("prefill", "miss")] == out[("decode", "miss")] == 2

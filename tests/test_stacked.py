@@ -278,7 +278,7 @@ def test_stacked_accounting():
     st_arr = StackedPlanArrays.from_entries(_entries(_ragged_luts(5)))
     assert st_arr.nbytes > 0
     assert 0.0 <= st_arr.padding_frac < 1.0
-    # tables_nbytes prices a full lut_tables dict (used by serve_bench)
+    # tables_nbytes prices a full lut_tables dict (used by launch/serve)
     tabs = {"backend": "gather", "sites": {"mlp": {"stacked":
                                                    st_arr.entry()}}}
     assert tables_nbytes(tabs) == st_arr.nbytes
