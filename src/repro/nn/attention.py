@@ -123,18 +123,24 @@ def decode_attend(
     q: jax.Array,          # (B, 1, H, Dh)
     k_cache: jax.Array,    # (B, Tmax, KV, Dh) — seq dim tp-sharded
     v_cache: jax.Array,
-    pos: jax.Array,        # scalar: current position (0-based)
+    k_new: jax.Array,      # (B, 1, KV, Dh): the new token's key and value
+    v_new: jax.Array,
+    pos: jax.Array,        # scalar: the new token's position (0-based)
     k_scale: jax.Array | None = None,  # (B, Tmax, KV) int8-cache scales
     v_scale: jax.Array | None = None,
     exp_fn=None,
 ) -> jax.Array:
-    """Single-token decode against a full cache (entries > pos masked).
+    """Single-token decode against the cached positions before ``pos``
+    and the token's own key and value, taken beside the cache, not from
+    it (as :func:`latent_decode_attend`): the caller writes the token's
+    entries once, after every layer, and the step copies no cache.
 
     With FAST_STREAM the scores dot accumulates in the stream dtype (the
     contraction is only Dh=128 wide — safe) which avoids the CPU-XLA
     bf16->f32 materialization of the whole cache; the value contraction
     (Tmax wide) always accumulates in f32.  int8 caches (k_scale/v_scale
-    given) dequantize at the consumer.
+    given) dequantize at the consumer; ``k_new``/``v_new`` come in the
+    dtype the cache reads as.
     """
     from .layers import FAST_STREAM
 
@@ -147,19 +153,21 @@ def decode_attend(
     if k_scale is not None:
         kc = kc.astype(q.dtype) * k_scale[..., None].astype(q.dtype)
         vc = vc.astype(q.dtype) * v_scale[..., None].astype(q.dtype)
-    if FAST_STREAM:
-        s = jnp.einsum("bqkgd,btkd->bkgqt", qg, kc).astype(jnp.float32)
-    else:
-        s = jnp.einsum("bqkgd,btkd->bkgqt", qg, kc,
-                       preferred_element_type=jnp.float32)
-    s = s * (dh ** -0.5)
-    valid = jnp.arange(tmax)[None] <= pos
-    s = jnp.where(valid[None, None, None], s, NEG_INF)
+
+    def scores(k):
+        if FAST_STREAM:
+            return jnp.einsum("bqkgd,btkd->bkgqt", qg, k).astype(jnp.float32)
+        return jnp.einsum("bqkgd,btkd->bkgqt", qg, k,
+                          preferred_element_type=jnp.float32)
+
+    s = jnp.concatenate([scores(kc), scores(k_new)], -1) * (dh ** -0.5)
+    t = jnp.arange(tmax + 1)
+    s = jnp.where(((t < pos) | (t == tmax))[None, None, None], s, NEG_INF)
     p = masked_softmax(s, exp_fn)
-    out = jnp.einsum(
-        "bkgqt,btkd->bqkgd", p.astype(vc.dtype), vc,
-        preferred_element_type=jnp.float32,
-    ).astype(q.dtype)
+    dot = functools.partial(jnp.einsum, "bkgqt,btkd->bqkgd",
+                            preferred_element_type=jnp.float32)
+    out = (dot(p[..., :tmax].astype(vc.dtype), vc)
+           + dot(p[..., tmax:].astype(v_new.dtype), v_new)).astype(q.dtype)
     return out.reshape(b, 1, h, dh)
 
 

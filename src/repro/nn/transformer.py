@@ -335,10 +335,15 @@ def _quantize_kv(x: jax.Array):
 def _decode_attn(p, x, cfg, k_cache, v_cache, pos, *, window=None,
                  ring_pos=None, rope=True, scales=None, lut_tables=None,
                  layer=None):
-    """Single-token attention against a cache; returns (out, k_new, v_new).
+    """Single-token attention; returns (out, k, v).
 
-    ``scales``: (k_scale, v_scale) for int8 caches — quantize at write,
-    dequantize at read; k/v returns become ((cache, scale), ...) pairs.
+    Against a full cache (``window`` None) the cache is only read and
+    ``k``/``v`` are the token's entries (B, 1, KV, Dh) in the cache's
+    dtype, which the caller writes at ``pos`` after every layer.
+    ``scales``: (k_scale, v_scale) for int8 caches — quantize the token,
+    dequantize at read; ``k``/``v`` are then (int8 entries, scales)
+    pairs.  Against a ring (``window``) the token is written into the
+    ring first and ``k``/``v`` are the updated rings.
     """
     b = x.shape[0]
     q = jnp.einsum("btd,dq->btq", x, p["wq"]).reshape(
@@ -358,40 +363,30 @@ def _decode_attn(p, x, cfg, k_cache, v_cache, pos, *, window=None,
     exp_fn = site_act(cfg, lut_tables, sites.ATTN_EXP, layer)
     if window is None and scales is not None:
         k_scale, v_scale = scales
-        kq, ks = _quantize_kv(k)
-        vq, vs = _quantize_kv(v)
-        k_cache = jax.lax.dynamic_update_slice(k_cache, kq, (0, pos, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, vq, (0, pos, 0, 0))
-        k_scale = jax.lax.dynamic_update_slice(
-            k_scale, ks.astype(k_scale.dtype), (0, pos, 0))
-        v_scale = jax.lax.dynamic_update_slice(
-            v_scale, vs.astype(v_scale.dtype), (0, pos, 0))
-        out = decode_attend(q, k_cache, v_cache, pos,
-                            k_scale=k_scale, v_scale=v_scale, exp_fn=exp_fn)
-        out = shard(out, "dp", None, "tp", None)
-        out = jnp.einsum("btq,qd->btd", out.reshape(b, 1, cfg.q_dim),
-                         p["wo"])
-        return out, (k_cache, k_scale), (v_cache, v_scale)
-    if window is None:
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k.astype(k_cache.dtype), (0, pos, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v.astype(v_cache.dtype), (0, pos, 0, 0))
-        out = decode_attend(q, k_cache, v_cache, pos, exp_fn=exp_fn)
+        (kq, ks), (vq, vs) = _quantize_kv(k), _quantize_kv(v)
+        ks, vs = ks.astype(k_scale.dtype), vs.astype(v_scale.dtype)
+        deq = lambda c, sc: c.astype(q.dtype) * sc[..., None].astype(q.dtype)
+        out = decode_attend(q, k_cache, v_cache, deq(kq, ks), deq(vq, vs),
+                            pos, k_scale=k_scale, v_scale=v_scale,
+                            exp_fn=exp_fn)
+        k, v = (kq, ks), (vq, vs)
+    elif window is None:
+        k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
+        out = decode_attend(q, k_cache, v_cache, k, v, pos, exp_fn=exp_fn)
     else:
         w = k_cache.shape[1]
         slot = pos % w
-        k_cache = jax.lax.dynamic_update_slice(
+        k = jax.lax.dynamic_update_slice(
             k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
+        v = jax.lax.dynamic_update_slice(
             v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0))
         slots = jnp.arange(w)
         stored = pos - ((pos - slots) % w)
-        out = ring_decode_attend(q, k_cache, v_cache, stored, pos, window,
+        out = ring_decode_attend(q, k, v, stored, pos, window,
                                  exp_fn=exp_fn)
     out = shard(out, "dp", None, "tp", None)
     out = jnp.einsum("btq,qd->btd", out.reshape(b, 1, cfg.q_dim), p["wo"])
-    return out, k_cache, v_cache
+    return out, k, v
 
 
 # =========================================================================

@@ -55,23 +55,40 @@ def decoder_prefill(params, cfg, batch, max_seq: int | None = None,
     return logits, cache
 
 
+def _layer_row(a, li):
+    """Layer ``li``'s rows of a stacked ``(L, ...)`` cache."""
+    return jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False)
+
+
+def _write_at(cache, entries: dict, pos):
+    """``cache`` with each ``entries[name]``, the token's entries of every
+    layer ``(L, B, 1, ...)``, written at sequence position ``pos``: one
+    in-place update a step when the caller donates the cache."""
+    cache = dict(cache)
+    for name, val in entries.items():
+        cache[name] = jax.lax.dynamic_update_slice(
+            cache[name], val, (0, 0, pos) + (0,) * (val.ndim - 3))
+    return cache
+
+
 def decoder_decode_step(params, cfg, cache, tokens, pos,
                         lut_tables=None):
+    """One token through every layer: each layer reads its rows of the
+    cache and attends over them and the token's own key and value; the
+    token's entries of all layers are written at ``pos`` after the last
+    one (an int8 cache's with their scales)."""
     x = _decoder_embed(params, cfg, tokens)
     int8 = "k_scale" in cache
 
     def body(x, inp, layer):
+        p, li = inp
+        row = lambda name: _layer_row(cache[name], li)
         rs = site_act(cfg, lut_tables, sites.NORM_RSQRT, layer)
-        if int8:
-            p, kc, vc, ksc, vsc = inp
-            h, (kc, ksc), (vc, vsc) = _decode_attn(
-                p, rms_norm(x, p["ln1"], cfg.norm_eps, rs), cfg, kc, vc,
-                pos, scales=(ksc, vsc), lut_tables=lut_tables, layer=layer)
-        else:
-            p, kc, vc = inp
-            h, kc, vc = _decode_attn(
-                p, rms_norm(x, p["ln1"], cfg.norm_eps, rs), cfg, kc, vc,
-                pos, lut_tables=lut_tables, layer=layer)
+        h, k, v = _decode_attn(
+            p, rms_norm(x, p["ln1"], cfg.norm_eps, rs), cfg, row("k"),
+            row("v"), pos,
+            scales=(row("k_scale"), row("v_scale")) if int8 else None,
+            lut_tables=lut_tables, layer=layer)
         x = x + h
         hin = rms_norm(x, p["ln2"], cfg.norm_eps, rs)
         if cfg.moe:
@@ -86,23 +103,17 @@ def decoder_decode_step(params, cfg, cache, tokens, pos,
                 lut_tables=lut_tables, layer=layer)
         else:
             h = mlp_block(p, hin, cfg, lut_tables, layer=layer)
-        out = (kc, vc, ksc, vsc) if int8 else (kc, vc)
-        return x + h, out
+        new = ({"k": k[0], "v": v[0], "k_scale": k[1], "v_scale": v[1]}
+               if int8 else {"k": k, "v": v})
+        return x + h, new
 
-    if int8:
-        xs = (params["blocks"], cache["k"], cache["v"], cache["k_scale"],
-              cache["v_scale"])
-        x, (ks, vs, kss, vss) = run_layers(body, x, xs,
-                                           lut_tables=lut_tables)
-        new_cache = {"k": ks, "v": vs, "k_scale": kss, "v_scale": vss}
-    else:
-        x, (ks, vs) = run_layers(
-            body, x, (params["blocks"], cache["k"], cache["v"]),
-            lut_tables=lut_tables)
-        new_cache = {"k": ks, "v": vs}
+    n = cache["k"].shape[0]
+    x, new = run_layers(
+        body, x, (params["blocks"], jnp.arange(n, dtype=jnp.int32)),
+        lut_tables=lut_tables)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = project_logits(x, params["lm_head"], cfg, lut_tables)
-    return logits, new_cache
+    return logits, _write_at(cache, new, pos)
 
 
 # =========================================================================
@@ -130,10 +141,9 @@ def latent_decode_step(params, cfg, cache, tokens, pos, lut_tables=None):
 
     def layer_fn(p, x, counts, li, lut_layer, expert_layer):
         rs = site_act(cfg, lut_tables, sites.NORM_RSQRT, lut_layer)
-        row = lambda a: jax.lax.dynamic_index_in_dim(a, li, 0,
-                                                     keepdims=False)
         h, new = _mla_decode(p, rms_norm(x, p["ln1"], cfg.norm_eps, rs), cfg,
-                             row(cache["c_kv"]), row(cache["k_pe"]), pos,
+                             _layer_row(cache["c_kv"], li),
+                             _layer_row(cache["k_pe"], li), pos,
                              lut_tables=lut_tables, layer=lut_layer)
         x = x + h
         y, routed = _latent_ffn(p, rms_norm(x, p["ln2"], cfg.norm_eps, rs),
@@ -145,12 +155,9 @@ def latent_decode_step(params, cfg, cache, tokens, pos, lut_tables=None):
     x, counts, (c_new, pe_new) = latent_layers(
         params, cfg, x, layer_fn, cache.get("expert_counts"),
         lut_tables=lut_tables)
-    cache = dict(cache)
+    cache = _write_at(cache, {"c_kv": c_new, "k_pe": pe_new}, pos)
     if counts is not None:
         cache["expert_counts"] = counts
-    for name, val in (("c_kv", c_new), ("k_pe", pe_new)):
-        cache[name] = jax.lax.dynamic_update_slice(cache[name], val,
-                                                   (0, 0, pos, 0))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return project_logits(x, params["lm_head"], cfg, lut_tables), cache
 
@@ -189,10 +196,11 @@ def encdec_decode_step(params, cfg, cache, tokens, pos, lut_tables=None):
     x = embed_lookup(params["embed"], tokens)
 
     def body(x, inp, layer):
-        p, kc, vc, xk, xv = inp
+        p, li, xk, xv = inp
         rs = site_act(cfg, lut_tables, sites.NORM_RSQRT, layer)
-        h, kc, vc = _decode_attn(
-            p, rms_norm(x, p["ln1"], cfg.norm_eps, rs), cfg, kc, vc, pos,
+        h, k, v = _decode_attn(
+            p, rms_norm(x, p["ln1"], cfg.norm_eps, rs), cfg,
+            _layer_row(cache["k"], li), _layer_row(cache["v"], li), pos,
             lut_tables=lut_tables, layer=layer)
         x = x + h
         xin = rms_norm(x, p["lnx"], cfg.norm_eps, rs)
@@ -205,16 +213,17 @@ def encdec_decode_step(params, cfg, cache, tokens, pos, lut_tables=None):
         x = x + h
         h = mlp_block(p, rms_norm(x, p["ln2"], cfg.norm_eps, rs), cfg,
                       lut_tables, layer=layer)
-        return x + h, (kc, vc)
+        return x + h, {"k": k, "v": v}
 
-    x, (ks, vs) = run_layers(
+    n = cache["k"].shape[0]
+    x, new = run_layers(
         body, x,
-        (params["dec_blocks"], cache["k"], cache["v"], cache["xk"],
+        (params["dec_blocks"], jnp.arange(n, dtype=jnp.int32), cache["xk"],
          cache["xv"]),
         lut_tables=lut_tables)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = project_logits(x, params["lm_head"], cfg, lut_tables)
-    return logits, {"k": ks, "v": vs, "xk": cache["xk"], "xv": cache["xv"]}
+    return logits, _write_at(cache, new, pos)
 
 
 # =========================================================================

@@ -167,6 +167,12 @@ def generate(cfg, params, batch: dict, new_tokens: int, *,
     (patch prefix included) plus ``new_tokens``.  ``all_logits`` keeps
     every decode step's logits, not only the first one's.
 
+    The single-device decode program takes the decode state donated:
+    each step's state is used once, so the step writes the token's cache
+    entries in place instead of copying the cache.  The share of the
+    state's bytes it aliases is the gauge ``decode_cache_aliased_share``,
+    set on every call under an entered ``Telemetry``.
+
     The compiled programs (prefill, decode, and the ``kv_int8`` replay)
     are kept across calls, each under its program and traced function,
     ``cfg``, ``max_seq``, the identity of ``lut_tables`` (or of
@@ -246,12 +252,14 @@ def generate(cfg, params, batch: dict, new_tokens: int, *,
             if serve is not None:
                 return serve.lower_decode(params, cache, tok, pos0)
             return jax.jit(lambda p, c, tk, pos: decode_step(
-                p, cfg, c, tk, pos, lut_tables=lut_tables)).lower(
-                params, cache, tok, pos0)
+                p, cfg, c, tk, pos, lut_tables=lut_tables),
+                donate_argnums=1).lower(params, cache, tok, pos0)
 
         dec_exe, dec_compile_s = _program(
             "decode", key("decode", decode_step, params, cache, tok, pos0),
             owner, lower_decode)
+        if serve is None:
+            _gauge_aliased_share(dec_exe, cache)
         outs = []
         t0 = time.perf_counter()
         with obs.span("decode", batch=b, new_tokens=new_tokens):
@@ -278,6 +286,22 @@ def generate(cfg, params, batch: dict, new_tokens: int, *,
             prefill_compile_s=pre_compile_s, prefill_s=pre_s,
             decode_compile_s=dec_compile_s, decode_s=dec_s,
             decode_program=dec_exe, expert_counts=counts)
+
+
+def _gauge_aliased_share(exe, cache) -> None:
+    """Set ``decode_cache_aliased_share``: the share of the decode state's
+    bytes on the device (tile padding included, as the compiler counts
+    them) that the compiled decode program writes in place of its input;
+    1.0 when the donated state is aliased whole.  Left unset where the
+    backend reports no memory analysis."""
+    mem = exe.memory_analysis()
+    alias = getattr(mem, "alias_size_in_bytes", None)
+    if alias is None:
+        return
+    nbytes = sum(x.on_device_size_in_bytes() for x in jax.tree.leaves(cache))
+    obs.gauge("decode_cache_aliased_share", alias / nbytes,
+              help="share of the decode state's bytes the decode program "
+                   "aliases")
 
 
 def _count_assignments(counts: np.ndarray) -> None:

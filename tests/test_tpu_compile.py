@@ -193,11 +193,11 @@ def test_fused_matmul_lut_pallas_compiles(one_chip, compiled_kernels, stack):
     assert _tpu_kernel(_compile(fn, *args), "lut_fused_matmul")
 
 
-def test_full_width_decode_step_with_pallas_tables_compiles(
-        one_chip, compiled_kernels, stack):
+def _full_width_decode_step(one_chip, stack, donate: bool):
     """qwen3-0.6b's whole decode step, batch 8 against a 1024-deep cache,
-    with the stacked Pallas MLP tables: the kernel is in the program, and
-    keeps its name once inlined into the served step."""
+    with the stacked Pallas MLP tables, compiled for one v5e chip (the
+    cache donated, as ``generate()`` runs it, or not).  Returns the
+    compiled step and the cache's spec."""
     import dataclasses
 
     cfg = dataclasses.replace(get_config("qwen3-0.6b"), lut_activation=True)
@@ -211,9 +211,38 @@ def test_full_width_decode_step_with_pallas_tables_compiles(
     cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, batch, max_seq)))
     tok = _spec((batch, 1), jnp.int32, one_chip)
     pos = _spec((), jnp.int32, one_chip)
-    compiled = _compile(
+    compiled = jax.jit(
         lambda p, c, t, i: decode_step(p, cfg, c, t, i, lut_tables=tables),
-        params, cache, tok, pos)
+        donate_argnums=1 if donate else ()).lower(
+        params, cache, tok, pos).compile()
+    return compiled, cache
+
+
+def test_full_width_decode_step_with_pallas_tables_compiles(
+        one_chip, compiled_kernels, stack):
+    """The kernel is in the served decode step, and keeps its name once
+    inlined into it."""
+    compiled, _ = _full_width_decode_step(one_chip, stack, donate=False)
     assert _tpu_kernel(compiled, "lut_act_stacked")
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 16e9   # fits one v5e chip's HBM
+
+
+def test_full_width_decode_step_writes_the_donated_cache_in_place(
+        one_chip, compiled_kernels, stack):
+    """With the cache donated, the step aliases the whole cache to its
+    output and no op copies a whole-cache-shaped ``bf16[28,8,1024,8,128]``
+    buffer: the token's entries are written in place, after the layers."""
+    import re
+
+    compiled, cache = _full_width_decode_step(one_chip, stack, donate=True)
+    shape = "bf16[28,8,1024,8,128]"
+    op = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\S+?)\{[^}]*\} ([\w-]+)\(")
+    copies = [m.group(1) for m in map(op.match,
+                                      compiled.as_text().splitlines())
+              if m and m.group(2) == shape
+              and (m.group(3) == "copy" or m.group(1).startswith("copy"))]
+    assert copies == []
+    cache_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
